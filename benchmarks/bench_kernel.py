@@ -1,11 +1,13 @@
 """Pallas tile-kernel microbenchmark: scatter-PB vs tile oracle vs the
 kernel's structural cost model.
 
-On CPU the Pallas kernel runs in interpret mode (not a wall-clock signal);
-what we benchmark here is (a) the *scatter* path vs the *dense tile* path in
-XLA:CPU — the structural advantage that motivates the TPU kernel — and (b)
-the kernel's analytic MXU utilisation per tile configuration (the numbers
-that justify the default_tile choice in kernels/ops.py).
+The Pallas kernel itself does not run here: it compiles only for the TPU,
+where ``chip_smoke.py`` runs it. What this section times is (a) the
+*scatter* path vs the *dense tile* oracle (``kernels.ref``, the same
+contraction in plain jnp) on the local backend — the structural advantage
+that motivates the TPU kernel — and (b) the kernel's analytic MXU
+utilisation per tile configuration (the numbers that justify the
+default_tile choice in kernels/ops.py).
 """
 from __future__ import annotations
 

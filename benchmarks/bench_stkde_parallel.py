@@ -28,10 +28,31 @@ SRC = str(pathlib.Path(__file__).resolve().parent.parent / "src")
 
 # All subprocess timing goes through repro.obs.timeit (one warmup +
 # block_until_ready code path); spans and metrics are exported through the
-# RESULT json and merged into the parent's tracer/registry.
+# RESULT json and merged into the parent's tracer/registry. Every row names
+# the devices of the process that measured it.
+_EMIT = r"""
+import json
+import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, names):
+    return jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
+
+
+def emit(rows):
+    from repro.obs import metrics, trace
+
+    d = jax.devices()
+    rows["device"] = {"platform": d[0].platform, "kind": d[0].device_kind,
+                      "count": len(d)}
+    rows["_trace_events"] = trace.get_tracer().export_events()
+    rows["_metrics"] = metrics.export()
+    print("RESULT" + json.dumps(rows))
+"""
+
 _SUBPROC = r"""
 import json
-import repro.compat
 import numpy as np, jax
 from repro.core import pb, bench_suite
 from repro.distributed.stkde_dist import STRATEGIES
@@ -45,7 +66,7 @@ pts = inst.points()
 seq = timeit(lambda: pb(pts, dom), name="parallel.seq_pb_sym",
              instance={name!r}).best
 rows = {{"instance": {name!r}, "seq_pb_sym_s": seq}}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 want = np.asarray(pb(pts, dom))
 for strat in ("dr", "dd", "pd", "dd_lpt"):
     fn = STRATEGIES[strat]
@@ -60,14 +81,11 @@ for strat in ("dr", "dd", "pd", "dd_lpt"):
     except ValueError as e:
         rows[strat + "_s"] = None
         rows[strat + "_note"] = str(e)[:60]
-rows["_trace_events"] = trace.get_tracer().export_events()
-rows["_metrics"] = metrics.export()
-print("RESULT" + json.dumps(rows))
+emit(rows)
 """
 
 _RECONCILE_SUBPROC = r"""
 import json
-import repro.compat
 import jax
 from repro.core import bench_suite
 from repro.obs import metrics, reconcile, trace
@@ -78,17 +96,14 @@ dom = inst.domain()
 pts = inst.points()
 # 3-axis mesh: pod serves as hybrid's rep axis / pd_xyt's X cut; the
 # worker-2D strategies span (data, model) and leave pod replicated
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 out = reconcile.run(pts, dom, mesh, reps={reps})
 out["instance"] = {name!r}
-out["_trace_events"] = trace.get_tracer().export_events()
-out["_metrics"] = metrics.export()
-print("RESULT" + json.dumps(out))
+emit(out)
 """
 
 _CHAOS_SUBPROC = r"""
 import json
-import repro.compat
 import numpy as np, jax
 from repro.core import pb, bench_suite
 from repro.core.api import stkde
@@ -99,7 +114,7 @@ suite = bench_suite(max_voxels=500_000, max_points=8_000)
 inst = suite[{name!r}]
 dom = inst.domain()
 pts = inst.points()
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 want = np.asarray(pb(pts, dom))
 reps = {reps}
 clean = timeit(lambda: stkde(pts, dom, mesh=mesh, strategy="pd"),
@@ -119,14 +134,11 @@ rows = {{"instance": {name!r}, "bench": "chaos", "spec": {spec!r},
         "retries": c.get("resilience.retries", 0),
         "fallbacks": c.get("resilience.fallbacks", 0),
         "gave_up": c.get("resilience.gave_up", 0)}}
-rows["_trace_events"] = trace.get_tracer().export_events()
-rows["_metrics"] = metrics.export()
-print("RESULT" + json.dumps(rows))
+emit(rows)
 """
 
 _CHUNKED_SUBPROC = r"""
 import json, os, tempfile
-import repro.compat
 import numpy as np, jax
 from repro.core import get_instance, pb
 from repro.core.api import stkde_chunked
@@ -136,7 +148,7 @@ from repro.obs import metrics, timeit, trace
 inst = get_instance({name!r}).scaled(max_voxels=300_000, max_points={n})
 dom = inst.domain()
 chunk = {chunk}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 # reference: the same points in one monolithic shot (the path the old
 # bench_suite 8k-point cap protected); the stream is deterministic, so a
@@ -161,15 +173,19 @@ rows = {{"instance": inst.name, "bench": "chunked", "n": int(inst.n),
         "chunked_overhead_pct":
             100.0 * (chunked - mono) / mono if mono else None,
         "coverage": res.report["coverage"], "correct": ok}}
-rows["_trace_events"] = trace.get_tracer().export_events()
-rows["_metrics"] = metrics.export()
-print("RESULT" + json.dumps(rows))
+emit(rows)
 """
 
 _sub_pid = 0   # synthetic pid per subprocess for the merged Chrome trace
 
 
 def _run_sub(code: str, n_dev: int = 8) -> dict:
+    """Run ``code`` in a child on ``n_dev`` virtual XLA:CPU devices.
+
+    The child never asks for the chip (the parent may hold it), and each
+    row it returns carries ``device`` with platform "cpu": these sections
+    time XLA:CPU, not the TPU.
+    """
     global _sub_pid
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
@@ -179,7 +195,7 @@ def _run_sub(code: str, n_dev: int = 8) -> dict:
     # its spec explicitly), so the ambient injection env must not leak
     # into direct-strategy timing subprocesses
     env.pop("REPRO_FAULTS", None)
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
+    proc = subprocess.run([sys.executable, "-c", _EMIT + code], env=env,
                           capture_output=True, text=True, timeout=1200)
     if proc.returncode != 0:
         raise RuntimeError(proc.stderr[-2000:])

@@ -4,7 +4,8 @@
 
 Sections:
   table3     sequential algorithms (paper Table 3)
-  parallel   multi-device strategy speedups (Figs. 8/10/11/13/15)
+  parallel   multi-device strategy speedups (Figs. 8/10/11/13/15), on
+             8 virtual XLA:CPU devices in a child process
   ddover     DD decomposition overhead (Fig. 9)
   coloring   critical path / scheduling study (Fig. 12)
   kernel     Pallas tile-kernel structural benchmark
@@ -13,7 +14,9 @@ Sections:
   chunked    crash-safe chunked execution at 32k points (journal overhead)
 
 Output: ``name,us_per_call,derived`` CSV lines to stdout + JSON to
-results/bench/.
+results/bench/. Rows measured in a child process carry ``device`` (platform,
+kind, count): the parallel, chaos and chunked sections time XLA:CPU.
+``chip_smoke.py`` at the repository root is the run on the TPU.
 
 With ``--trace``, also writes results/bench/trace.json (Chrome trace —
 load in chrome://tracing or Perfetto) and metrics.json, and the parallel
@@ -49,6 +52,8 @@ def main() -> None:
                     help="add the fault-injection benchmark (recovery "
                          "overhead; REPRO_FAULTS overrides the spec)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     all_results = {}
 

@@ -9,6 +9,7 @@ import jax
 
 from repro.configs import ARCHS, reduced
 from repro.models import init_params
+from repro.compile_cache import enable_compile_cache
 from repro.serve import ServingEngine, EngineConfig
 
 
@@ -24,6 +25,7 @@ def serve(cfg, params, lens, continuous):
 
 
 def main():
+    enable_compile_cache()
     cfg = reduced(ARCHS["mistral-nemo-12b"])   # GQA family, tiny dims
     params = init_params(cfg, jax.random.PRNGKey(0))
     lens = [8, 8, 12, 12, 12, 16, 8, 16]

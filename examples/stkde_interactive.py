@@ -13,6 +13,7 @@ import time
 import numpy as np
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Domain, pb, clustered_events
 
 
@@ -33,6 +34,7 @@ def ascii_map(slice2d, width=48, height=20):
 
 
 def main():
+    enable_compile_cache()
     dom0 = Domain(gx=148, gy=194, gt=112, sres=1, tres=1, hs=3, ht=1)
     pts = clustered_events(11_056, dom0, seed=1)   # Dengue-sized
     print(f"events: {len(pts)}, domain {dom0.describe()}\n")

@@ -12,10 +12,12 @@ import argparse
 import tempfile
 
 from repro.configs import ARCHS
+from repro.compile_cache import enable_compile_cache
 from repro.launch import train as train_driver
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ckpt-dir", default=None)

@@ -3,8 +3,6 @@
 A production-grade JAX framework reproducing Saule et al. (2017) and
 re-architecting its algorithms (PB-SYM + DR/DD/PD/SCHED/REP parallel
 strategies) for multi-pod TPU meshes, embedded in a full training/serving
-substrate (see DESIGN.md).
+substrate.
 """
 __version__ = "1.0.0"
-
-from . import compat  # noqa: E402,F401  (installs JAX version shims)

@@ -100,7 +100,8 @@ def stkde(
 
     strategy: "auto" | "dr" | "dd" | "pd" | "dd_lpt" | "hybrid"
               (single-device when mesh is None: scatter PB-SYM, or the
-              Pallas tiled kernel with use_tiled_kernel=True).
+              Pallas tiled kernel, compiled for the TPU, with
+              use_tiled_kernel=True).
     validate: typed input validation at this boundary (see
               ``validate_inputs``).
     fallback: on mesh strategy build/execution failure or non-finite
@@ -129,8 +130,9 @@ def stkde(
         if use_tiled_kernel:
             from repro.kernels import stkde_tiled
 
-            return ensure_finite(stkde_tiled(pts, dom, ks=ks, kt=kt),
-                                 "stkde.tiled")
+            return ensure_finite(
+                stkde_tiled(pts, dom, ks=ks, kt=kt, mode="compiled"),
+                "stkde.tiled")
         return ensure_finite(
             _pb(pts, dom, variant="sym", ks=ks, kt=kt), "stkde.pb"
         )
@@ -148,7 +150,8 @@ def stkde(
 
         tile = (math.ceil(dom.Gx / A), math.ceil(dom.Gy / B), dom.Gt)
         loads = bucketing.bucket_points_home(pts, dom, tile).counts
-        strategy, _ = _plan.choose(dom, len(pts), shape, loads.reshape(-1))
+        strategy, _ = _plan.choose(dom, len(pts), shape, loads.reshape(-1),
+                                   hw=_plan.default_hw())
         if strategy in ("hybrid", "pd_xyt") and rep_axis is None:
             strategy = "pd"
     fn = STRATEGIES[strategy]

@@ -27,6 +27,10 @@ from repro.obs import trace as obs_trace
 
 from .geometry import Domain
 
+# Coordinate of an empty or padding bucket slot: far outside every domain,
+# where every kernel is zero, so parked slots contribute nothing.
+PARK = -1e8
+
 
 @dataclasses.dataclass
 class Buckets:

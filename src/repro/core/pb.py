@@ -25,8 +25,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from .geometry import Domain
 from . import kernels_math as km
 
@@ -164,9 +162,9 @@ def _pb_impl(
     grid = jnp.zeros((gsz + 1,), dtype=jnp.float32)  # +1 slot absorbs drops
     # Inside shard_map the scan carry must carry the same varying-manual-axes
     # tag as the point shards feeding it.
-    vma = compat.vma_of(points)
+    vma = jax.typeof(points).vma
     if vma:
-        grid = compat.pcast(grid, tuple(vma), to="varying")
+        grid = jax.lax.pcast(grid, tuple(vma), to="varying")
 
     def body(grid, blk):
         p, v = blk

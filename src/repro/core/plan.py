@@ -11,7 +11,8 @@ the roofline analysis uses):
 
     time = init(HBM memset)  +  point-work(FLOPs, x imbalance)  +  collectives
 
-and returns the argmin. Hardware constants default to TPU v5e.
+and returns the argmin. Hardware constants default to TPU v5e;
+``default_hw()`` picks them by the device JAX runs on.
 """
 from __future__ import annotations
 
@@ -137,15 +138,26 @@ def calibrate_host(rows, base: Hardware = HOST_SEED,
     return out
 
 
-def default_hw() -> Hardware:
-    """The Hardware model matching the active JAX backend (HOST on cpu)."""
-    try:
-        import jax
+# Peaks by ``device_kind``. v5e: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s per chip).
+PEAKS: Dict[str, Hardware] = {"TPU v5 lite": V5E}
 
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    return HOST if backend == "cpu" else V5E
+
+def default_hw() -> Hardware:
+    """The Hardware model of JAX's first device: HOST on the CPU, the
+    ``PEAKS`` entry of its ``device_kind`` otherwise. A device kind with no
+    entry is an error, not a default."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return HOST
+    try:
+        return PEAKS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware peaks for device_kind {dev.device_kind!r}; "
+            "add them to repro.core.plan.PEAKS") from None
 
 
 def _point_work_flops(dom: Domain, n_eff: float) -> float:

@@ -1,6 +1,6 @@
 """Multi-device STKDE strategies (shard_map) — the paper's §4/§5 on a TPU mesh.
 
-Strategy map (see DESIGN.md §2 for the full paper→TPU table):
+Strategy map (paper strategy -> TPU mesh layout):
 
   stkde_dr      PB-SYM-DR   points sharded over all devices, per-device full
                             grid, all-reduce. Pleasingly parallel; comm = grid.
@@ -29,9 +29,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map, pcast
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.geometry import Domain
 from repro.core import bucketing, kernels_math as km
@@ -40,7 +39,7 @@ from repro.obs import trace as obs_trace
 from repro.resilience import faults as _faults
 from . import partition
 
-PARK = -1e8  # parked coordinate for invalid/padded points
+PARK = bucketing.PARK
 
 
 def _pad_tile_grid(points, valid, A, B):
@@ -63,6 +62,15 @@ def _mesh_sizes(mesh: Mesh, axes) -> Tuple[int, ...]:
     return tuple(mesh.shape[a] for a in axes)
 
 
+def _on_mesh(mesh: Mesh, lead, *arrays):
+    """Place host arrays on ``mesh``, split over their leading dimensions
+    (``lead`` mesh axes) as the strategy's shard_map expects, so each
+    device receives only its own shard."""
+    sharding = NamedSharding(mesh, P(*lead))
+    out = tuple(jax.device_put(a, sharding) for a in arrays)
+    return out if len(out) > 1 else out[0]
+
+
 def _park_invalid(pts, valid):
     """Move invalid bucket slots far outside every domain."""
     return jnp.where(valid[..., None] > 0, pts, PARK)
@@ -79,7 +87,7 @@ def prepare_dr(
     npad = bucketing.round_up(max(n, Ptot), Ptot)
     full = np.full((npad, 3), PARK, dtype=np.float32)
     full[:n] = pts
-    return jnp.asarray(full)
+    return _on_mesh(mesh, (tuple(axes),), full)
 
 
 def stkde_dr(
@@ -161,7 +169,7 @@ def prepare_dd(
     bpts, bval = _pad_tile_grid(
         b.points.reshape(na, nb, b.cap, 3),
         b.valid.reshape(na, nb, b.cap).astype(np.float32), A, B)
-    return jnp.asarray(bpts), jnp.asarray(bval)
+    return _on_mesh(mesh, axes, bpts, bval)
 
 
 def stkde_dd(
@@ -232,7 +240,7 @@ def prepare_pd(
     bp, bv = _pad_tile_grid(
         b.points.reshape(na, nb, b.cap, 3),
         b.valid.reshape(na, nb, b.cap).astype(np.float32), A, B)
-    return jnp.asarray(bp), jnp.asarray(bv)
+    return _on_mesh(mesh, axes, bp, bv)
 
 
 def stkde_pd(
@@ -372,7 +380,7 @@ def prepare_pd_xt(
     bp, bv = _pad_tile_grid(
         b.points.reshape(na, nt, b.cap, 3),
         b.valid.reshape(na, nt, b.cap).astype(np.float32), A, B)
-    return jnp.asarray(bp), jnp.asarray(bv)
+    return _on_mesh(mesh, axes, bp, bv)
 
 
 def build_pd_xt(dom: Domain, mesh: Mesh, axes, n: int,
@@ -496,7 +504,7 @@ def prepare_pd_xyt(
     vv = np.zeros((A, B, C, b.cap), dtype=np.float32)
     pp[:na, :nb, :nt] = b.points
     vv[:na, :nb, :nt] = b.valid.astype(np.float32)
-    return jnp.asarray(pp), jnp.asarray(vv)
+    return _on_mesh(mesh, axes, pp, vv)
 
 
 def build_pd_xyt(dom: Domain, mesh: Mesh, axes, n: int,
@@ -628,7 +636,7 @@ def prepare_hybrid(
     p_of = pos // R
     dpts[r_of, :, :, p_of] = np.transpose(src, (2, 0, 1, 3))
     dval[r_of, :, :, p_of] = np.transpose(val, (2, 0, 1)).astype(np.float32)
-    return jnp.asarray(dpts), jnp.asarray(dval)
+    return _on_mesh(mesh, (rep_axis,) + tuple(axes), dpts, dval)
 
 
 def stkde_hybrid(
@@ -693,7 +701,7 @@ def prepare_dd_lpt(
             dpts[p, s] = flat_pts[t]
             dval[p, s] = flat_val[t]
             dpos[p, s] = (ti * bx, tj * by, tk * bt)
-    args = (jnp.asarray(dpts), jnp.asarray(dval), jnp.asarray(dpos))
+    args = _on_mesh(mesh, (tuple(axes),), dpts, dval, dpos)
     ctx = {"tile": tile, "k": k, "cap": capn, "ntiles": b.ntiles}
     return args, ctx
 
@@ -729,7 +737,8 @@ def build_dd_lpt(dom: Domain, mesh: Mesh, axes, n: int,
         w = (tc[None, :] - pts_t[:, 2:3]) / dom.ht
         Ks = ks(u[:, :, None], v[:, None, :]) * norm
         Kt = kt(w) * val_t[:, None]
-        return jnp.einsum("pxy,pt->xyt", Ks, Kt)
+        return jnp.einsum("pxy,pt->xyt", Ks, Kt,
+                          precision=jax.lax.Precision.HIGHEST)
 
     def f(pts_blk, val_blk, pos_blk):  # (1,k,cap,3), (1,k,cap), (1,k,3)
         tiles = jax.vmap(one_tile)(pts_blk[0], val_blk[0], pos_blk[0])
@@ -746,7 +755,7 @@ def build_dd_lpt(dom: Domain, mesh: Mesh, axes, n: int,
                 (pos_blk[0, s, 0], pos_blk[0, s, 1], pos_blk[0, s, 2]),
             )
 
-        g0 = pcast(
+        g0 = jax.lax.pcast(
             jnp.zeros((Gxp, Gyp, Gtp), jnp.float32), (ax, ay), to="varying"
         )
         g = jax.lax.fori_loop(0, k, place, g0)

@@ -5,13 +5,14 @@ stkde_tile.py — PB-SYM tile accumulation as an MXU GEMM (pallas_call +
 ops.py        — jit'd public wrappers (bucketing + kernel + slice)
 ref.py        — pure-jnp oracles for allclose testing
 """
-from .ops import stkde_tiled, default_tile
+from .ops import stkde_tiled, default_tile, tiled_inputs
 from .stkde_tile import stkde_tiles_pallas
 from .ref import stkde_tiles_ref
 
 __all__ = [
     "stkde_tiled",
     "default_tile",
+    "tiled_inputs",
     "stkde_tiles_pallas",
     "stkde_tiles_ref",
 ]

@@ -2,8 +2,9 @@
 
 ``stkde_tiled(points, dom)`` is the TPU performance path for single-device
 STKDE: host-side overlap bucketing -> Pallas tile-GEMM kernel -> slice to the
-domain grid. On CPU it runs the kernel in interpret mode (bitwise-faithful to
-the kernel body, slow) — use ``core.pb`` for fast CPU execution.
+domain grid. It compiles the kernel for the TPU; CPU callers ask for
+``mode="interpret"`` (bitwise-faithful to the kernel body, slow) — use
+``core.pb`` for fast CPU execution.
 """
 from __future__ import annotations
 
@@ -16,16 +17,17 @@ from repro.core.geometry import Domain
 from repro.core import bucketing
 from repro.core import kernels_math as km
 from . import ref as _ref
-from .stkde_tile import stkde_tiles_pallas
+from .stkde_tile import CHUNK, lane_layout, stkde_tiles_pallas
 
 
 def default_tile(dom: Domain) -> Tuple[int, int, int]:
     """Tile shape tuned for the TPU memory hierarchy.
 
-    * bx, by multiples of 8 with bx*by a multiple of 256 keeps the GEMM's
-      output panel MXU-aligned (bx*by plays the "M" dimension).
-    * bt (the "N" dimension) padded to >= 8; temporal bandwidths are small so
-      bt stays modest and the accumulator (bx*by*bt*4B) fits VMEM easily.
+    * bx, by multiples of 8: bx*by is the output block's lane dimension
+      (the GEMM's "N"), 1024 at the default 32x32.
+    * bt (the "M" dimension, the output block's sublanes) a multiple of 8;
+      temporal bandwidths are small so bt stays modest and the accumulator
+      (bt*bx*by*4B) fits VMEM easily.
     """
     bx = int(min(bucketing.round_up(dom.Gx, 8), 32))
     by = int(min(bucketing.round_up(dom.Gy, 8), 32))
@@ -33,51 +35,43 @@ def default_tile(dom: Domain) -> Tuple[int, int, int]:
     return (bx, by, bt)
 
 
+def tiled_inputs(
+    points: np.ndarray,
+    dom: Domain,
+    tile: Optional[Tuple[int, int, int]] = None,
+    cap: Optional[int] = None,
+    chunk: int = CHUNK,
+) -> Tuple[np.ndarray, Tuple[int, int, int], int]:
+    """Host-side kernel input: ``(lane-layout points, tile, chunk)``."""
+    if tile is None:
+        tile = default_tile(dom)
+    b = bucketing.bucket_points_overlap(points, dom, tile, cap=cap)
+    lanes, chunk = lane_layout(b.points, b.valid, chunk)
+    return lanes, tile, chunk
+
+
 def stkde_tiled(
     points: np.ndarray,
     dom: Domain,
     tile: Optional[Tuple[int, int, int]] = None,
     cap: Optional[int] = None,
-    chunk: int = 256,
+    chunk: int = CHUNK,
     ks: km.SpatialKernel = km.DEFAULT_KS,
     kt: km.TemporalKernel = km.DEFAULT_KT,
-    interpret: Optional[bool] = None,
     use_ref: bool = False,
-    mode: str = "auto",
+    mode: str = "compiled",
 ) -> jnp.ndarray:
     """STKDE density grid via the tiled PB-SYM GEMM kernel.
 
-    ``mode`` ("auto" | "interpret" | "compiled") selects how the Pallas
-    kernel executes — see ``stkde_tiles_pallas``. ``"auto"`` compiles on
-    TPU and interprets elsewhere. The three-state ``interpret`` bool is
-    deprecated (True -> "interpret", False -> "compiled"); passing it
-    emits a DeprecationWarning.
+    ``mode`` ("compiled" | "interpret") selects how the Pallas kernel
+    executes — see ``stkde_tiles_pallas``. Compiled mode needs a TPU.
     """
     pts = np.asarray(points, dtype=np.float32)
-    n = len(pts)
-    if tile is None:
-        tile = default_tile(dom)
-    b = bucketing.bucket_points_overlap(pts, dom, tile, cap=cap)
-    cap_eff = bucketing.round_up(b.cap, min(chunk, bucketing.round_up(b.cap, 8)))
-    if cap_eff != b.cap:
-        pad = cap_eff - b.cap
-        b_points = np.pad(b.points, ((0, 0),) * 3 + ((0, pad), (0, 0)))
-        b_valid = np.pad(b.valid, ((0, 0),) * 3 + ((0, pad),))
-    else:
-        b_points, b_valid = b.points, b.valid
-    chunk_eff = min(chunk, cap_eff)
-    # make chunk divide cap
-    while cap_eff % chunk_eff:
-        chunk_eff //= 2
-    args = (
-        jnp.asarray(b_points),
-        jnp.asarray(b_valid.astype(np.float32)),
-    )
+    lanes, tile, chunk = tiled_inputs(pts, dom, tile, cap, chunk)
+    lanes = jnp.asarray(lanes)
     if use_ref:
-        padded = _ref.stkde_tiles_ref(*args, dom, tile, n, ks, kt)
+        padded = _ref.stkde_tiles_ref(lanes, dom, tile, len(pts), ks, kt)
     else:
-        padded = stkde_tiles_pallas(
-            *args, dom, tile, cap_eff, n, chunk_eff, ks, kt,
-            interpret=interpret, mode=mode,
-        )
+        padded = stkde_tiles_pallas(lanes, dom, tile, len(pts), chunk,
+                                    ks, kt, mode=mode)
     return padded[: dom.Gx, : dom.Gy, : dom.Gt]

@@ -12,8 +12,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 
 # ------------------------------------------------------------------- init
 def dense_init(key, shape, in_axis: int = -2, scale: float = 1.0,
@@ -34,7 +32,7 @@ def rms_norm(x, weight, eps: float = 1e-5):
     # barrier: keeps the fp32 upcast from being fused across the TP
     # all-reduce feeding the norm (§Perf iteration 3; ~2% on zamba2,
     # neutral elsewhere — measured both ways on dbrx)
-    x = compat.optimization_barrier(x)
+    x = jax.lax.optimization_barrier(x)
     x32 = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     out = x32 * jax.lax.rsqrt(var + eps)

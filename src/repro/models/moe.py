@@ -137,7 +137,7 @@ def moe_apply_a2a(cfg, p, x, mesh) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed import sharding as _sh
 
     dt = x.dtype

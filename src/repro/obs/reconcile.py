@@ -49,8 +49,9 @@ TERMS = ("init_s", "compute_s", "comm_s", "total_s")
 
 
 def _default_hw():
-    """V5E on TPU backends; calibrated host constants on CPU (so the
-    smoke-run relative errors are about calibration, not CPU != TPU)."""
+    """The device's peaks (``plan.PEAKS``) on a TPU; calibrated host
+    constants on CPU (so the smoke-run relative errors are about
+    calibration, not CPU != TPU)."""
     from repro.core import plan
 
     return plan.default_hw()

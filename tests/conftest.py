@@ -13,8 +13,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 import pytest
 
-import repro.compat  # noqa: F401  (JAX version shims before test imports)
-
 try:
     import hypothesis  # noqa: F401
 except ModuleNotFoundError:  # container has no hypothesis; use the shim

@@ -132,3 +132,25 @@ class TestPlanner:
                               "dd_lpt", "hybrid"}
         for v in table.values():
             assert v["total_s"] > 0
+
+    @pytest.mark.parametrize("platform,kind,want", [
+        ("cpu", "cpu", "HOST"),
+        ("tpu", "TPU v5 lite", "V5E"),
+        ("tpu", "TPU v99 imaginary", None),
+    ])
+    def test_default_hw_by_device_kind(self, monkeypatch, platform, kind,
+                                       want):
+        """Peaks come from one table keyed by device_kind; a kind with no
+        entry raises instead of being priced as some other chip."""
+        import types
+
+        import jax
+        from repro.core import plan
+
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a: [dev])
+        if want is None:
+            with pytest.raises(ValueError, match="device_kind"):
+                plan.default_hw()
+        else:
+            assert plan.default_hw() is getattr(plan, want)

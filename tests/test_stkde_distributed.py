@@ -44,6 +44,20 @@ def test_all_strategies_match_reference():
         check(stkde_hybrid(pts, dom, mesh3), want, "hybrid")
         from repro.distributed.stkde_dist import stkde_pd_xyt
         check(stkde_pd_xyt(pts, dom, mesh3), want, "pd_xyt")
+
+        # prepared points arrive on the mesh already split: each device
+        # holds its own shard, not the whole set
+        from repro.distributed.stkde_dist import (
+            prepare_dr, prepare_dd, prepare_pd)
+        w2 = ("data", "model")
+        full = prepare_dr(pts, dom, mesh, w2)
+        assert {s.data.shape for s in full.addressable_shards} == {
+            (full.shape[0] // 8, 3)}
+        for prep in (prepare_dd, prepare_pd):
+            for arr in prep(pts, dom, mesh, w2):
+                shards = arr.addressable_shards
+                assert len({s.device for s in shards}) == 8
+                assert {s.data.shape[:2] for s in shards} == {(1, 1)}
         """
     )
     run_with_devices(code, 8)

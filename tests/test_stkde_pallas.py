@@ -1,5 +1,6 @@
 """Pallas tile-kernel sweep tests: kernel (interpret mode) vs pure-jnp oracle
-vs the independent scatter formulation (core.pb)."""
+vs the independent scatter formulation (core.pb). Compiles for the chip are
+in test_chip_compile.py."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -35,7 +36,7 @@ def test_kernel_vs_scatter_sweep(grid, hs, ht, tile):
     )
     pts = _make(dom, 400, seed=hash(grid) % 1000)
     want = np.asarray(pb(pts, dom))
-    got = np.asarray(stkde_tiled(pts, dom, tile=tile))
+    got = np.asarray(stkde_tiled(pts, dom, tile=tile, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-8)
 
 
@@ -44,7 +45,7 @@ def test_kernel_chunk_sizes(chunk):
     dom = Domain(gx=32, gy=32, gt=16, sres=1.0, tres=1.0, hs=3.0, ht=2.0)
     pts = _make(dom, 600, seed=11)
     want = np.asarray(stkde_tiled(pts, dom, use_ref=True))
-    got = np.asarray(stkde_tiled(pts, dom, chunk=chunk))
+    got = np.asarray(stkde_tiled(pts, dom, chunk=chunk, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-9)
 
 
@@ -63,7 +64,7 @@ def test_kernel_nonunit_resolution_and_origin():
         axis=1,
     ).astype(np.float32)
     want = np.asarray(pb(pts, dom))
-    got = np.asarray(stkde_tiled(pts, dom))
+    got = np.asarray(stkde_tiled(pts, dom, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-8)
 
 
@@ -72,7 +73,7 @@ def test_kernel_paper_verbatim_kernel_funcs():
     pts = _make(dom, 200, seed=13)
     kw = dict(ks=km.ks_paper_verbatim, kt=km.kt_paper_verbatim)
     want = np.asarray(pb(pts, dom, variant="sym", **kw))
-    got = np.asarray(stkde_tiled(pts, dom, **kw))
+    got = np.asarray(stkde_tiled(pts, dom, mode="interpret", **kw))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-8)
 
 
@@ -87,7 +88,7 @@ def test_property_kernel_equals_scatter(n, hs, ht, seed):
     dom = Domain(gx=26, gy=22, gt=18, sres=1.0, tres=1.0, hs=hs, ht=ht)
     pts = _make(dom, n, seed=seed)
     want = np.asarray(pb(pts, dom))
-    got = np.asarray(stkde_tiled(pts, dom))
+    got = np.asarray(stkde_tiled(pts, dom, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
 
 
@@ -95,7 +96,7 @@ def test_empty_tiles_are_zero():
     """Points concentrated in one corner leave far tiles exactly zero."""
     dom = Domain(gx=64, gy=64, gt=16, sres=1.0, tres=1.0, hs=2.0, ht=1.0)
     pts = np.full((50, 3), 3.0, dtype=np.float32)
-    grid = np.asarray(stkde_tiled(pts, dom))
+    grid = np.asarray(stkde_tiled(pts, dom, mode="interpret"))
     assert grid[10:, 10:, :].sum() == 0.0
     assert grid[:8, :8, :8].sum() > 0
 
@@ -103,5 +104,15 @@ def test_empty_tiles_are_zero():
 def test_dtype_is_f32_accumulation():
     dom = Domain(gx=16, gy=16, gt=8, sres=1.0, tres=1.0, hs=2.0, ht=1.0)
     pts = _make(dom, 100, seed=17)
-    out = stkde_tiled(pts, dom)
+    out = stkde_tiled(pts, dom, mode="interpret")
     assert out.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("mode", ["compiled", "auto"])
+def test_kernel_interprets_only_on_request(mode):
+    """Off the TPU, any mode but "interpret" raises instead of silently
+    falling back to the interpreter."""
+    dom = Domain(gx=16, gy=16, gt=8, sres=1.0, tres=1.0, hs=2.0, ht=1.0)
+    pts = _make(dom, 50, seed=19)
+    with pytest.raises(ValueError, match="interpret"):
+        stkde_tiled(pts, dom, mode=mode)

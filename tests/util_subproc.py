@@ -21,9 +21,6 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     )
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    # JAX version shims (jax.shard_map, AxisType, ...) must be installed
-    # before snippets import those names straight from jax.
-    code = "import repro.compat\n" + code
     proc = subprocess.run(
         [sys.executable, "-c", code],
         env=env,
@@ -57,7 +54,6 @@ def popen_with_devices(code: str, n_devices: int = 8,
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if clean_faults:
         env.pop("REPRO_FAULTS", None)
-    code = "import repro.compat\n" + code
     return subprocess.Popen(
         [sys.executable, "-c", code],
         env=env,
