@@ -23,9 +23,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace as obs_trace
 from repro.resilience.degrade import ensure_finite
 from repro.resilience.errors import (
     DeviceLostError,
@@ -115,67 +117,101 @@ def stkde(
               or ``res.grid`` is the float64 accumulator grid; ``.report``
               carries coverage/recovery details).
     """
-    if chunk_size is not None or journal is not None or resume is not None:
-        return stkde_chunked(
-            points, dom, mesh=mesh, strategy=strategy, axes=axes,
-            rep_axis=rep_axis, ks=ks, kt=kt, chunk_size=chunk_size,
-            journal=resume if resume is not None else journal,
-            resume=resume is not None, validate=validate,
-        )
-    if validate:
-        pts = validate_inputs(points, dom)
-    else:
-        pts = np.asarray(points, dtype=np.float32)
-    if mesh is None:
-        if use_tiled_kernel:
-            from repro.kernels import stkde_tiled
+    with obs_trace.span("stkde") as root:
+        if chunk_size is not None or journal is not None or resume is not None:
+            root.set(path="chunked")
+            return stkde_chunked(
+                points, dom, mesh=mesh, strategy=strategy, axes=axes,
+                rep_axis=rep_axis, ks=ks, kt=kt, chunk_size=chunk_size,
+                journal=resume if resume is not None else journal,
+                resume=resume is not None, validate=validate,
+            )
+        with obs_trace.span("stkde.api.validate"):
+            if validate:
+                pts = validate_inputs(points, dom)
+            else:
+                pts = np.asarray(points, dtype=np.float32)
+        root.set(n=len(pts), voxels=dom.Gx * dom.Gy * dom.Gt)
+        if mesh is None:
+            root.set(path="tiled" if use_tiled_kernel else "pb")
+            if use_tiled_kernel:
+                from repro.kernels import stkde_tiled
 
-            return ensure_finite(
-                stkde_tiled(pts, dom, ks=ks, kt=kt, mode="compiled"),
-                "stkde.tiled")
-        return ensure_finite(
-            _pb(pts, dom, variant="sym", ks=ks, kt=kt), "stkde.pb"
-        )
+                out = stkde_tiled(pts, dom, ks=ks, kt=kt, mode="compiled")
+                return _checked(out, "stkde.tiled")
+            return _checked(_pb_single(pts, dom, ks, kt), "stkde.pb")
 
-    from repro.distributed import STRATEGIES
-    from . import bucketing
+        from repro.distributed import STRATEGIES
 
-    if strategy == "auto":
-        A = mesh.shape[axes[0]]
-        B = mesh.shape[axes[1]]
-        shape = (
-            (mesh.shape[rep_axis], A, B) if rep_axis is not None else (A, B)
-        )
+        if strategy == "auto":
+            with obs_trace.span("stkde.api.plan"):
+                strategy = _auto_strategy(dom, len(pts), mesh, axes,
+                                          rep_axis, pts)
+        root.set(path=strategy)
+        fn = STRATEGIES[strategy]
+        kw = dict(axes=axes, ks=ks, kt=kt)
+        if strategy == "hybrid":
+            kw["rep_axis"] = rep_axis or "pod"
+        elif strategy == "pd_xyt" and len(axes) == 2:
+            # 3-D split needs a third mesh axis: the rep axis becomes the
+            # X cut
+            kw["axes"] = (rep_axis or "pod",) + tuple(axes)
+        try:
+            return _checked(fn(pts, dom, mesh, **kw), f"stkde.{strategy}")
+        except (ReproError, ValueError) as e:
+            if not fallback or strategy == "dr":
+                raise
+            from repro import obs
+
+            obs.counter("resilience.fallbacks").inc()
+            obs.counter(f"resilience.fallbacks.stkde.{strategy}").inc()
+            with obs.span("resilience.fallback", frm=strategy, to="dr",
+                          error=type(e).__name__):
+                out = STRATEGIES["dr"](pts, dom, mesh, axes=axes, ks=ks,
+                                       kt=kt)
+            return _checked(out, "stkde.dr")
+
+
+def _pb_single(pts: np.ndarray, dom: Domain, ks, kt) -> jnp.ndarray:
+    """Scatter PB-SYM on the default device, in the phases a mesh strategy
+    names: the points' transfer, then the jitted call."""
+    with obs_trace.span("stkde.pb", n=len(pts)):
+        with obs_trace.span("stkde.pb.bucket"):
+            with obs_trace.span("transfer.to_device", bytes=pts.nbytes):
+                dev = jnp.asarray(pts)
+        with obs_trace.span("stkde.pb.dispatch"):
+            return _pb(dev, dom, variant="sym", ks=ks, kt=kt)
+
+
+def _auto_strategy(dom: Domain, n: int, mesh, axes, rep_axis,
+                   pts: Optional[np.ndarray] = None) -> str:
+    """The planner's strategy for ``n`` points on ``mesh``, priced with the
+    home-bucket loads of ``pts`` where the points are at hand."""
+    A = mesh.shape[axes[0]]
+    B = mesh.shape[axes[1]]
+    shape = (mesh.shape[rep_axis], A, B) if rep_axis is not None else (A, B)
+    loads = None
+    if pts is not None:
         import math
+
+        from . import bucketing
 
         tile = (math.ceil(dom.Gx / A), math.ceil(dom.Gy / B), dom.Gt)
         loads = bucketing.bucket_points_home(pts, dom, tile).counts
-        strategy, _ = _plan.choose(dom, len(pts), shape, loads.reshape(-1),
-                                   hw=_plan.default_hw())
-        if strategy in ("hybrid", "pd_xyt") and rep_axis is None:
-            strategy = "pd"
-    fn = STRATEGIES[strategy]
-    kw = dict(axes=axes, ks=ks, kt=kt)
-    if strategy == "hybrid":
-        kw["rep_axis"] = rep_axis or "pod"
-    elif strategy == "pd_xyt" and len(axes) == 2:
-        # 3-D split needs a third mesh axis: the rep axis becomes the X cut
-        kw["axes"] = (rep_axis or "pod",) + tuple(axes)
-    try:
-        return ensure_finite(fn(pts, dom, mesh, **kw),
-                             f"stkde.{strategy}")
-    except (ReproError, ValueError) as e:
-        if not fallback or strategy == "dr":
-            raise
-        from repro import obs
+        loads = loads.reshape(-1)
+    strategy, _ = _plan.choose(dom, n, shape, loads, hw=_plan.default_hw())
+    if strategy in ("hybrid", "pd_xyt") and rep_axis is None:
+        strategy = "pd"
+    return strategy
 
-        obs.counter("resilience.fallbacks").inc()
-        obs.counter(f"resilience.fallbacks.stkde.{strategy}").inc()
-        with obs.span("resilience.fallback", frm=strategy, to="dr",
-                      error=type(e).__name__):
-            out = STRATEGIES["dr"](pts, dom, mesh, axes=axes, ks=ks,
-                                   kt=kt)
-        return ensure_finite(out, "stkde.dr")
+
+def _checked(grid, tag: str):
+    """The build's last two phases: wait for the device, then check that
+    the grid is finite on the host (``ensure_finite`` copies it back)."""
+    with obs_trace.span("stkde.api.wait"):
+        grid = jax.block_until_ready(grid)
+    with obs_trace.span("stkde.api.check_finite", bytes=grid.nbytes):
+        return ensure_finite(grid, tag)
 
 
 # ------------------------------------------------------------------ chunked
@@ -236,15 +272,7 @@ def _replan_after_loss(dom: Domain, n_total: int, mesh, axes, rep_axis):
     new_mesh = _mesh_lib.shrink_mesh(mesh, 1)
     if new_mesh is None:
         return None, "local"
-    A = new_mesh.shape[axes[0]]
-    B = new_mesh.shape[axes[1]]
-    shape = ((new_mesh.shape[rep_axis], A, B) if rep_axis is not None
-             else (A, B))
-    strat, _ = _plan.choose(dom, n_total, shape, None,
-                            hw=_plan.default_hw())
-    if strat in ("hybrid", "pd_xyt") and rep_axis is None:
-        strat = "pd"
-    return new_mesh, strat
+    return new_mesh, _auto_strategy(dom, n_total, new_mesh, axes, rep_axis)
 
 
 def stkde_chunked(
@@ -315,21 +343,9 @@ def stkde_chunked(
     if mesh is None:
         strat = "local"
     elif strategy == "auto":
-        A, B = mesh.shape[axes[0]], mesh.shape[axes[1]]
-        shape = ((mesh.shape[rep_axis], A, B) if rep_axis is not None
-                 else (A, B))
-        if is_array:
-            import math
-
-            tile = (math.ceil(dom.Gx / A), math.ceil(dom.Gy / B), dom.Gt)
-            loads = bucketing.bucket_points_home(points, dom, tile).counts
-            loads = loads.reshape(-1)
-        else:
-            loads = None  # streams can't be pre-bucketed; use defaults
-        strat, _ = _plan.choose(dom, n_total, shape, loads,
-                                hw=_plan.default_hw())
-        if strat in ("hybrid", "pd_xyt") and rep_axis is None:
-            strat = "pd"
+        # streams cannot be pre-bucketed: the planner prices defaults
+        strat = _auto_strategy(dom, n_total, mesh, axes, rep_axis,
+                               points if is_array else None)
     else:
         strat = strategy
 

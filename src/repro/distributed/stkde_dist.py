@@ -19,6 +19,12 @@ Strategy map (paper strategy -> TPU mesh layout):
 
 All strategies are normalization-consistent with ``core.pb`` (global n) and
 are cross-tested for exact agreement in tests/test_stkde_distributed.py.
+
+Every ``stkde_<s>`` opens the span ``stkde.<s>`` and names its phases the
+same way: ``stkde.<s>.bucket`` (the points' host layout and their
+``transfer.to_device``), ``stkde.<s>.dispatch`` (building the ``jax.jit``
+and calling it: retrace, lowering, cache fetch, enqueue) and, where the
+shards are put back together, ``stkde.<s>.reassemble``.
 """
 from __future__ import annotations
 
@@ -67,8 +73,19 @@ def _on_mesh(mesh: Mesh, lead, *arrays):
     (``lead`` mesh axes) as the strategy's shard_map expects, so each
     device receives only its own shard."""
     sharding = NamedSharding(mesh, P(*lead))
-    out = tuple(jax.device_put(a, sharding) for a in arrays)
+    with obs_trace.span("transfer.to_device",
+                        bytes=sum(a.nbytes for a in arrays)):
+        out = tuple(jax.device_put(a, sharding) for a in arrays)
     return out if len(out) > 1 else out[0]
+
+
+def _assemble_xy(out, dom: Domain, A: int, B: int, gx_loc: int,
+                 gy_loc: int):
+    """The (A, B, gx_loc, gy_loc, Gt) device blocks as the (Gx, Gy, Gt)
+    grid."""
+    out = out.reshape(A, B, gx_loc, gy_loc, dom.Gt)
+    out = out.transpose(0, 2, 1, 3, 4).reshape(A * gx_loc, B * gy_loc, dom.Gt)
+    return out[: dom.Gx, : dom.Gy, :]
 
 
 def _park_invalid(pts, valid):
@@ -106,11 +123,10 @@ def stkde_dr(
     """
     n = int(n_total) if n_total is not None else len(points)
     with obs_trace.span("stkde.dr", n=n, mesh=str(dict(mesh.shape))):
-        with obs_trace.span("stkde.dr.prepare"):
+        with obs_trace.span("stkde.dr.bucket"):
             full = prepare_dr(points, dom, mesh, axes)
-            fn = build_dr(dom, mesh, axes, n, ks, kt)
-        with obs_trace.span("stkde.dr.execute", blocking=False):
-            return fn(full)
+        with obs_trace.span("stkde.dr.dispatch"):
+            return build_dr(dom, mesh, axes, n, ks, kt)(full)
 
 
 def build_dr(dom: Domain, mesh: Mesh, axes, n: int,
@@ -189,13 +205,10 @@ def stkde_dd(
     with obs_trace.span("stkde.dd", n=n, mesh=str(dict(mesh.shape))):
         with obs_trace.span("stkde.dd.bucket"):
             bpts, bval = prepare_dd(points, dom, mesh, axes, cap=cap)
-        fn = build_dd(dom, mesh, axes, n, ks, kt)
-        with obs_trace.span("stkde.dd.execute", blocking=False):
-            out = fn(bpts, bval)
-            out = out.reshape(A, B, gx_loc, gy_loc, dom.Gt)
-            out = out.transpose(0, 2, 1, 3, 4).reshape(
-                A * gx_loc, B * gy_loc, dom.Gt)
-            return out[: dom.Gx, : dom.Gy, :]
+        with obs_trace.span("stkde.dd.dispatch"):
+            out = build_dd(dom, mesh, axes, n, ks, kt)(bpts, bval)
+        with obs_trace.span("stkde.dd.reassemble"):
+            return _assemble_xy(out, dom, A, B, gx_loc, gy_loc)
 
 
 def build_dd(dom: Domain, mesh: Mesh, axes, n: int,
@@ -253,7 +266,6 @@ def stkde_pd(
     kt: km.TemporalKernel = km.DEFAULT_KT,
     n_total: Optional[int] = None,
     _rep_axis: Optional[str] = None,
-    _pts_override=None,
 ) -> jnp.ndarray:
     """Work-efficient owner-computes + halo exchange (PB-SYM-PD)."""
     ax, ay = axes
@@ -270,25 +282,25 @@ def stkde_pd(
         )
     strat = "pd" if _rep_axis is None else "hybrid"
     with obs_trace.span(f"stkde.{strat}", n=n, mesh=str(dict(mesh.shape))):
-        if _pts_override is None:
-            with obs_trace.span(f"stkde.{strat}.bucket"):
+        with obs_trace.span(f"stkde.{strat}.bucket"):
+            if _rep_axis is None:
                 bpts, bval = prepare_pd(pts, dom, mesh, axes, cap=cap)
-        else:  # hybrid path: (R, A, B, cap, 3) sharded over rep too
-            bpts, bval = _pts_override
-        # fault site dist.halo: an injected OOM here models a failed
-        # strategy build (halo buffers are the PD-only allocation); the
-        # api-level fallback then reroutes the query to the dr baseline.
-        _faults.fault_point("dist.halo")
-        fn = build_pd(dom, mesh, axes, n, ks, kt, rep_axis=_rep_axis)
-        with obs_trace.span(f"stkde.{strat}.execute", blocking=False):
-            out = fn(bpts, bval)
-            out = out.reshape(A, B, gx_loc, gy_loc, dom.Gt)
-            out = out.transpose(0, 2, 1, 3, 4).reshape(
-                A * gx_loc, B * gy_loc, dom.Gt)
+            else:  # (R, A, B, cap, 3), sharded over rep too
+                bpts, bval = prepare_hybrid(pts, dom, mesh, axes,
+                                            rep_axis=_rep_axis, cap=cap)
+        with obs_trace.span(f"stkde.{strat}.dispatch"):
+            # fault site dist.halo: an injected OOM here models a failed
+            # strategy build (halo buffers are the PD-only allocation); the
+            # api-level fallback then reroutes the query to the dr
+            # baseline.
+            _faults.fault_point("dist.halo")
+            out = build_pd(dom, mesh, axes, n, ks, kt,
+                           rep_axis=_rep_axis)(bpts, bval)
+        with obs_trace.span(f"stkde.{strat}.reassemble"):
             # nan-kind injection poisons the folded halos; callers
             # validate via resilience.degrade.ensure_finite
             return _faults.poison(
-                "dist.halo", out[: dom.Gx, : dom.Gy, :])
+                "dist.halo", _assemble_xy(out, dom, A, B, gx_loc, gy_loc))
 
 
 def build_pd(dom: Domain, mesh: Mesh, axes, n: int,
@@ -477,13 +489,16 @@ def stkde_pd_xt(
     n = int(n_total) if n_total is not None else len(pts)
     gx_loc = math.ceil(dom.Gx / A)
     gt_loc = math.ceil(dom.Gt / B)
-    bpts, bval = prepare_pd_xt(pts, dom, mesh, axes, cap=cap)
-    fn = build_pd_xt(dom, mesh, axes, n, ks, kt)
-    out = fn(bpts, bval)
-    out = out.reshape(A, B, gx_loc, dom.Gy, gt_loc)
-    out = out.transpose(0, 2, 3, 1, 4).reshape(
-        A * gx_loc, dom.Gy, B * gt_loc)
-    return out[: dom.Gx, :, : dom.Gt]
+    with obs_trace.span("stkde.pd_xt", n=n, mesh=str(dict(mesh.shape))):
+        with obs_trace.span("stkde.pd_xt.bucket"):
+            bpts, bval = prepare_pd_xt(pts, dom, mesh, axes, cap=cap)
+        with obs_trace.span("stkde.pd_xt.dispatch"):
+            out = build_pd_xt(dom, mesh, axes, n, ks, kt)(bpts, bval)
+        with obs_trace.span("stkde.pd_xt.reassemble"):
+            out = out.reshape(A, B, gx_loc, dom.Gy, gt_loc)
+            out = out.transpose(0, 2, 3, 1, 4).reshape(
+                A * gx_loc, dom.Gy, B * gt_loc)
+            return out[: dom.Gx, :, : dom.Gt]
 
 
 def prepare_pd_xyt(
@@ -597,13 +612,16 @@ def stkde_pd_xyt(
     gx_loc = math.ceil(dom.Gx / A)
     gy_loc = math.ceil(dom.Gy / B)
     gt_loc = math.ceil(dom.Gt / C)
-    bpts, bval = prepare_pd_xyt(pts, dom, mesh, axes, cap=cap)
-    fn = build_pd_xyt(dom, mesh, axes, n, ks, kt)
-    out = fn(bpts, bval)
-    out = out.reshape(A, B, C, gx_loc, gy_loc, gt_loc)
-    out = out.transpose(0, 3, 1, 4, 2, 5).reshape(
-        A * gx_loc, B * gy_loc, C * gt_loc)
-    return out[: dom.Gx, : dom.Gy, : dom.Gt]
+    with obs_trace.span("stkde.pd_xyt", n=n, mesh=str(dict(mesh.shape))):
+        with obs_trace.span("stkde.pd_xyt.bucket"):
+            bpts, bval = prepare_pd_xyt(pts, dom, mesh, axes, cap=cap)
+        with obs_trace.span("stkde.pd_xyt.dispatch"):
+            out = build_pd_xyt(dom, mesh, axes, n, ks, kt)(bpts, bval)
+        with obs_trace.span("stkde.pd_xyt.reassemble"):
+            out = out.reshape(A, B, C, gx_loc, gy_loc, gt_loc)
+            out = out.transpose(0, 3, 1, 4, 2, 5).reshape(
+                A * gx_loc, B * gy_loc, C * gt_loc)
+            return out[: dom.Gx, : dom.Gy, : dom.Gt]
 
 
 # ------------------------------------------------------------------ hybrid
@@ -655,13 +673,8 @@ def stkde_hybrid(
     Every bucket's points are dealt round-robin over the rep axis — the
     moldable-task replication of the paper expressed as a mesh dimension.
     """
-    pts = np.asarray(points, dtype=np.float32)
-    return stkde_pd(
-        pts, dom, mesh, axes, cap=cap, ks=ks, kt=kt, n_total=n_total,
-        _rep_axis=rep_axis,
-        _pts_override=prepare_hybrid(
-            pts, dom, mesh, axes, rep_axis=rep_axis, cap=cap),
-    )
+    return stkde_pd(points, dom, mesh, axes, cap=cap, ks=ks, kt=kt,
+                    n_total=n_total, _rep_axis=rep_axis)
 
 
 # ------------------------------------------------------------------ DD-LPT
@@ -799,13 +812,17 @@ def stkde_dd_lpt(
     """
     pts = np.asarray(points, dtype=np.float32)
     n = int(n_total) if n_total is not None else len(pts)
-    args, ctx = prepare_dd_lpt(pts, dom, mesh, axes, tile=tile, cap=cap)
-    fn = build_dd_lpt(
-        dom, mesh, axes, n, ctx["tile"], ctx["k"], ctx["cap"],
-        ctx["ntiles"], ks, kt,
-    )
-    out = fn(*args)
-    return out[: dom.Gx, : dom.Gy, : dom.Gt]
+    with obs_trace.span("stkde.dd_lpt", n=n, mesh=str(dict(mesh.shape))):
+        with obs_trace.span("stkde.dd_lpt.bucket"):
+            args, ctx = prepare_dd_lpt(pts, dom, mesh, axes, tile=tile,
+                                       cap=cap)
+        with obs_trace.span("stkde.dd_lpt.dispatch"):
+            out = build_dd_lpt(
+                dom, mesh, axes, n, ctx["tile"], ctx["k"], ctx["cap"],
+                ctx["ntiles"], ks, kt,
+            )(*args)
+        with obs_trace.span("stkde.dd_lpt.reassemble"):
+            return out[: dom.Gx, : dom.Gy, : dom.Gt]
 
 
 STRATEGIES = {

@@ -16,6 +16,7 @@ import numpy as np
 from repro.core.geometry import Domain
 from repro.core import bucketing
 from repro.core import kernels_math as km
+from repro.obs import trace as obs_trace
 from . import ref as _ref
 from .stkde_tile import CHUNK, lane_layout, stkde_tiles_pallas
 
@@ -64,14 +65,22 @@ def stkde_tiled(
     """STKDE density grid via the tiled PB-SYM GEMM kernel.
 
     ``mode`` ("compiled" | "interpret") selects how the Pallas kernel
-    executes — see ``stkde_tiles_pallas``. Compiled mode needs a TPU.
+    executes — see ``stkde_tiles_pallas``. Compiled mode needs a TPU. Opens
+    ``stkde.tiled`` with the phases a mesh strategy names (``.bucket``,
+    ``.dispatch``, ``.reassemble``).
     """
     pts = np.asarray(points, dtype=np.float32)
-    lanes, tile, chunk = tiled_inputs(pts, dom, tile, cap, chunk)
-    lanes = jnp.asarray(lanes)
-    if use_ref:
-        padded = _ref.stkde_tiles_ref(lanes, dom, tile, len(pts), ks, kt)
-    else:
-        padded = stkde_tiles_pallas(lanes, dom, tile, len(pts), chunk,
-                                    ks, kt, mode=mode)
-    return padded[: dom.Gx, : dom.Gy, : dom.Gt]
+    with obs_trace.span("stkde.tiled", n=len(pts)):
+        with obs_trace.span("stkde.tiled.bucket"):
+            lanes, tile, chunk = tiled_inputs(pts, dom, tile, cap, chunk)
+            with obs_trace.span("transfer.to_device", bytes=lanes.nbytes):
+                lanes = jnp.asarray(lanes)
+        with obs_trace.span("stkde.tiled.dispatch"):
+            if use_ref:
+                padded = _ref.stkde_tiles_ref(lanes, dom, tile, len(pts),
+                                              ks, kt)
+            else:
+                padded = stkde_tiles_pallas(lanes, dom, tile, len(pts),
+                                            chunk, ks, kt, mode=mode)
+        with obs_trace.span("stkde.tiled.reassemble"):
+            return padded[: dom.Gx, : dom.Gy, : dom.Gt]

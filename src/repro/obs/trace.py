@@ -1,31 +1,51 @@
 """Nestable wall-clock tracing with Chrome-trace export.
 
-The repo's timing story in one place: every phase worth watching (bucketing,
-strategy execute, serve prefill/decode, train steps, benchmark reps) opens a
-``span``. Spans nest per thread, carry free-form attributes, and export to
-the Chrome trace-event JSON format (load in ``chrome://tracing`` or
-Perfetto). Optionally each span also mirrors into
-``jax.profiler.TraceAnnotation`` so host spans line up with device traces
-when a JAX profile is being captured.
+The repo's timing story in one place: every phase worth watching (an STKDE
+build's validation, bucketing, dispatch and output check, serve
+prefill/decode, train steps, benchmark reps) opens a ``span``. Spans nest
+per thread, carry free-form attributes, and export to the Chrome
+trace-event JSON format (load in ``chrome://tracing`` or Perfetto).
+Optionally each span also mirrors into ``jax.profiler.TraceAnnotation`` so
+host spans line up with device traces when a JAX profile is being captured.
+
+Once JAX is imported, its compile events are added to the innermost span
+open on the thread that compiled: ``compiles`` counts backend compiles (a
+persistent-cache fetch included) and ``compile_s`` sums the seconds of
+tracing, lowering and compiling.
+
+A tracer keeps its newest ``MAX_SPANS`` closed spans and counts the older
+ones it dropped (``Tracer.dropped``), so a long session stays bounded.
 
 Naming convention (see docs/observability.md): dotted lowercase
-``component.subject[.phase]`` — e.g. ``stkde.pd.execute``,
+``component.subject[.phase]`` — e.g. ``stkde.pd.dispatch``,
 ``serve.prefill``, ``train.step``, ``bench.table3.pb_sym``.
 
-Dependency-free: stdlib only; jax is touched lazily and only when
-mirroring is enabled.
+Dependency-free: stdlib only; jax is touched lazily: by mirroring, and by
+the compile listener once something else has imported jax.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Deque, Dict, Iterator, List, Optional
 
 _NS_PER_US = 1_000
+MAX_SPANS = 65_536        # closed spans a tracer keeps; older ones drop
+
+# jax.monitoring duration events of a compile; the backend one counts as
+# one compile (it includes a persistent-cache retrieval)
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    BACKEND_COMPILE,
+)
 
 
 @dataclasses.dataclass
@@ -78,10 +98,10 @@ class Tracer:
     def __init__(self, mirror_jax: bool = False):
         self._lock = threading.Lock()
         self._tls = threading.local()
-        self._spans: List[Span] = []
+        self._spans: Deque[Span] = collections.deque(maxlen=MAX_SPANS)
         self._foreign: List[Dict[str, Any]] = []   # ingested child events
         self._next_id = 0
-        self.enabled = True
+        self.dropped = 0          # closed spans pushed out by the cap
         self.mirror_jax = mirror_jax
         self.epoch_ns = time.perf_counter_ns()
 
@@ -94,9 +114,8 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs) -> Iterator[Span]:
-        if not self.enabled:
-            yield Span(name=name, start_ns=0)
-            return
+        if not _compile_listener_on and "jax" in sys.modules:
+            _listen_for_compiles()
         with self._lock:
             self._next_id += 1
             sid = self._next_id
@@ -123,6 +142,8 @@ class Tracer:
                 time.perf_counter_ns() - self.epoch_ns - sp.start_ns
             )
             with self._lock:
+                if len(self._spans) == self._spans.maxlen:
+                    self.dropped += 1
                 self._spans.append(sp)
 
     @staticmethod
@@ -176,10 +197,38 @@ class Tracer:
             self._spans.clear()
             self._foreign.clear()
             self._next_id = 0
+            self.dropped = 0
         self.epoch_ns = time.perf_counter_ns()
 
 
 _TRACER = Tracer()
+_compile_listener_on = False
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    """Add a JAX compile event to the innermost span the global tracer
+    has open on the thread that compiled."""
+    if event not in COMPILE_EVENTS:
+        return
+    stack = _TRACER._stack()
+    if not stack:
+        return
+    sp = stack[-1]
+    if event == BACKEND_COMPILE:
+        sp.attrs["compiles"] = sp.attrs.get("compiles", 0) + 1
+    sp.attrs["compile_s"] = sp.attrs.get("compile_s", 0.0) + secs
+
+
+def _listen_for_compiles() -> None:
+    """Register ``_on_duration`` with ``jax.monitoring``, once."""
+    global _compile_listener_on
+    with _TRACER._lock:
+        if _compile_listener_on:
+            return
+        _compile_listener_on = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def get_tracer() -> Tracer:

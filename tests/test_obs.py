@@ -25,6 +25,19 @@ def test_span_nesting_and_attrs():
     assert spans["inner"].start_ns >= spans["outer"].start_ns
 
 
+def test_retained_spans_stop_at_the_cap_and_count_the_dropped():
+    tr = trace.Tracer()
+    for i in range(trace.MAX_SPANS + 10):
+        with tr.span(f"s{i}"):
+            pass
+    kept = tr.spans()
+    assert len(kept) == trace.MAX_SPANS == 65_536 and tr.dropped == 10
+    assert kept[0].name == "s10" and kept[-1].name == f"s{trace.MAX_SPANS + 9}"
+    assert len(tr.to_chrome_trace()["traceEvents"]) == trace.MAX_SPANS
+    tr.clear()
+    assert tr.spans() == [] and tr.dropped == 0
+
+
 def test_global_span_helper_records():
     with trace.span("unit.test", k="v") as sp:
         pass
