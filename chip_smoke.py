@@ -2,13 +2,16 @@
 """Smoke run of the STKDE main path on the TPU, at published Table 2 sizes.
 
     python chip_smoke.py             # one chip
+    python chip_smoke.py --instances Dengue_Lr-Hb Flu_Mr-Lb
     python chip_smoke.py --chips 4   # four chips, the mesh strategies only
 
 One chip: PollenUS_Hr-Lb (588,189 points, 651x301x84 grid) and Flu_Mr-Hb
-(31,478 points, 233x615x1985 grid) through ``stkde()``: the scatter PB-SYM
-default and the Pallas tile kernel in compiled mode. Both grids are checked
-against a float64 NumPy evaluation of the paper's Algorithm 1 (VB) at a few
-hundred voxels, half of them around the densest voxel.
+(31,478 points, 233x615x1985 grid), or the Table 2 instances named, through
+``stkde()`` three ways: the scatter PB-SYM and the Pallas tile kernel in
+compiled mode, each forced, and the default, whose row names the path the
+planner chose. Every grid is checked against a float64 NumPy evaluation of
+the paper's Algorithm 1 (VB) at a few hundred voxels, half of them around
+the densest voxel.
 
 Four chips: Flu_Mr-Hb through every mesh strategy — dr, dd, pd, pd_xt and
 dd_lpt on a 2x2 (data, model) mesh, hybrid and pd_xyt on a 2x1x2
@@ -133,7 +136,14 @@ def kernel_is_compiled(pts, dom) -> bool:
     return "tpu_custom_call" in text
 
 
-def one_chip(names=("PollenUS_Hr-Lb", "Flu_Mr-Hb")) -> None:
+def chosen_path() -> str:
+    """The path of the last ``stkde()`` build, from its root span."""
+    from repro.obs import trace
+
+    return trace.get_tracer().spans("stkde")[-1].attrs["path"]
+
+
+def one_chip(names) -> None:
     import jax.numpy as jnp
     from repro.core import get_instance
     from repro.core.api import stkde
@@ -143,7 +153,8 @@ def one_chip(names=("PollenUS_Hr-Lb", "Flu_Mr-Hb")) -> None:
         dom, pts = inst.domain(), inst.points()
         base = {"instance": name, "n": len(pts),
                 "grid": list(dom.grid_shape), "Hs": dom.Hs, "Ht": dom.Ht}
-        pb_grid, first, run = timed(lambda: stkde(pts, dom, fallback=False))
+        pb_grid, first, run = timed(
+            lambda: stkde(pts, dom, use_tiled_kernel=False, fallback=False))
         voxels = sample_voxels(pb_grid, dom, inst.seed)
         want = vb_reference(pts, dom, voxels)
         scale = float(jnp.max(pb_grid))
@@ -165,7 +176,12 @@ def one_chip(names=("PollenUS_Hr-Lb", "Flu_Mr-Hb")) -> None:
                    "run_s": run, "max_err_rel": err(tk_grid),
                    "vs_scatter_rel": diff, "tpu_custom_call": True,
                    "peak_hbm_gb": peak_hbm_gb(), "fallbacks": fallbacks()})
-        del pb_grid, tk_grid
+        del tk_grid
+        grid, first, run = timed(lambda: stkde(pts, dom, fallback=False))
+        check_row({**base, "path": "default", "chose": chosen_path(),
+                   "first_call_s": first, "run_s": run,
+                   "max_err_rel": err(grid), "fallbacks": fallbacks()})
+        del pb_grid, grid
 
 
 def four_chips(name: str = "Flu_Mr-Hb") -> None:
@@ -208,6 +224,10 @@ def four_chips(name: str = "Flu_Mr-Hb") -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--instances", nargs="+", metavar="NAME",
+                    default=["PollenUS_Hr-Lb", "Flu_Mr-Hb"],
+                    help="Table 2 instances for one chip "
+                         "(repro.core.datasets.INSTANCES)")
     args = ap.parse_args(argv)
     os.environ.pop("REPRO_FAULTS", None)   # no ambient fault injection
     if not (ROOT / "src" / "repro").is_dir():
@@ -227,7 +247,7 @@ def main(argv=None) -> int:
     emit(compile_cache=enable_compile_cache(), jax=jax.__version__,
          hw=dataclasses.asdict(plan.default_hw()))
     try:
-        four_chips() if args.chips == 4 else one_chip()
+        four_chips() if args.chips == 4 else one_chip(args.instances)
     except SmokeError as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

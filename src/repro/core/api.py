@@ -1,7 +1,8 @@
 """Top-level STKDE public API: one call, strategy auto-selected.
 
     from repro.core.api import stkde
-    grid = stkde(points, dom)                       # single device
+    grid = stkde(points, dom)                       # single device, path
+                                                    # chosen by the planner
     grid = stkde(points, dom, mesh=mesh)            # auto strategy on mesh
     grid = stkde(points, dom, mesh=mesh, strategy="pd")
     res = stkde(points, dom, chunk_size=4096,       # crash-safe chunked run
@@ -27,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.resilience.degrade import ensure_finite
 from repro.resilience.errors import (
@@ -91,7 +93,7 @@ def stkde(
     rep_axis: Optional[str] = None,
     ks: km.SpatialKernel = km.DEFAULT_KS,
     kt: km.TemporalKernel = km.DEFAULT_KT,
-    use_tiled_kernel: bool = False,
+    use_tiled_kernel: Optional[bool] = None,
     validate: bool = True,
     fallback: bool = True,
     chunk_size: Optional[int] = None,
@@ -101,9 +103,13 @@ def stkde(
     """Space-time kernel density grid for ``points`` over ``dom``.
 
     strategy: "auto" | "dr" | "dd" | "pd" | "dd_lpt" | "hybrid"
-              (single-device when mesh is None: scatter PB-SYM, or the
-              Pallas tiled kernel, compiled for the TPU, with
-              use_tiled_kernel=True).
+              (mesh only).
+    use_tiled_kernel: the single-device path (mesh is None). None lets
+              the planner choose (``plan.choose_single``): the XLA scatter
+              PB-SYM, or the Pallas tile kernel compiled for the TPU,
+              whichever it prices cheaper for this grid, bandwidth and
+              point count; off the TPU always the scatter. True or False
+              forces the tile kernel or the scatter.
     validate: typed input validation at this boundary (see
               ``validate_inputs``).
     fallback: on mesh strategy build/execution failure or non-finite
@@ -133,8 +139,16 @@ def stkde(
                 pts = np.asarray(points, dtype=np.float32)
         root.set(n=len(pts), voxels=dom.Gx * dom.Gy * dom.Gt)
         if mesh is None:
-            root.set(path="tiled" if use_tiled_kernel else "pb")
-            if use_tiled_kernel:
+            if use_tiled_kernel is None:
+                with obs_trace.span("stkde.api.plan"):
+                    path, prices = _plan.choose_single(dom, len(pts))
+                root.set(priced_pb_s=prices["pb"],
+                         priced_tiled_s=prices["tiled"])
+            else:
+                path = "tiled" if use_tiled_kernel else "pb"
+            root.set(path=path)
+            obs_metrics.counter(f"stkde.path.{path}").inc()
+            if path == "tiled":
                 from repro.kernels import stkde_tiled
 
                 out = stkde_tiled(pts, dom, ks=ks, kt=kt, mode="compiled")

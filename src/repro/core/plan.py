@@ -11,8 +11,9 @@ the roofline analysis uses):
 
     time = init(HBM memset)  +  point-work(FLOPs, x imbalance)  +  collectives
 
-and returns the argmin. Hardware constants default to TPU v5e;
-``default_hw()`` picks them by the device JAX runs on.
+and returns the argmin. On one device ``choose_single`` prices the XLA
+scatter against the Pallas tile kernel the same way. Hardware constants
+default to TPU v5e; ``default_hw()`` picks them by the device JAX runs on.
 """
 from __future__ import annotations
 
@@ -37,6 +38,12 @@ class Hardware:
     hbm_bytes: float = 16e9      # per chip
     vpu_derate: float = 0.04     # scatter path ~ VPU: few % of MXU peak
     mxu_derate: float = 0.5      # tile-GEMM path: realistic MXU fraction
+    # single-device paths (``choose_single``): seconds per unit of work, a
+    # least-squares fit to second calls of both paths, each forced, on
+    # eleven Table 2 instances on one TPU v5e (PERF.md §6)
+    pb_update_s: float = 1.04e-8     # scatter: per cylinder update
+    tile_copy_s: float = 1.56e-6     # tile kernel: per overlap copy
+    tile_voxel_s: float = 2.6e-9     # tile kernel: per padded voxel
 
 
 V5E = Hardware()
@@ -52,6 +59,9 @@ HOST_SEED = Hardware(
     hbm_bytes=4e9,
     vpu_derate=1.0,      # scatter path on CPU is the same ALUs
     mxu_derate=1.0,
+    pb_update_s=1.8e-8,  # XLA:CPU scatter, one process (PERF.md §6)
+    tile_copy_s=math.inf,    # no compiled tile kernel off the TPU
+    tile_voxel_s=math.inf,
 )
 
 # Calibrated against results/bench/reconcile.json (mesh 2x2x2, n=8000, all
@@ -307,3 +317,35 @@ def choose(
     feas = {k: v for k, v in table.items() if v["feasible"] > 0}
     pick = min(feas or table, key=lambda k: (feas or table)[k]["total_s"])
     return pick, table
+
+
+def choose_single(dom: Domain, n: int, hw: Optional[Hardware] = None
+                  ) -> Tuple[str, Dict[str, float]]:
+    """The one-device path for ``n`` points on ``dom``, and each path's
+    price in seconds on ``hw`` (default ``default_hw()``).
+
+    ``pb``, the XLA scatter, costs one unit per cylinder update,
+    ``n (2Hs+1)^2 (2Ht+1)``. ``tiled``, the Pallas tile kernel under
+    ``kernels.ops.default_tile``, costs one unit per point copy that overlap
+    bucketing makes (each point lands in every tile its cylinder's box
+    meets, on average ``1 + 2H/b`` tiles a dimension) and one per voxel of
+    the padded grid. Neither price looks at where the points lie. The
+    cheaper path wins; off the TPU it is always "pb", since the tile kernel
+    compiles for the TPU only.
+    """
+    import jax
+    from repro.kernels.ops import default_tile
+
+    hw = default_hw() if hw is None else hw
+    tile = default_tile(dom)
+    nt = bucketing.num_tiles(dom, tile)
+    copies = float(n)
+    for b, k, h in zip(tile, nt, (dom.Hs, dom.Hs, dom.Ht)):
+        copies *= min(1.0 + 2.0 * h / b, k)
+    padded = math.prod(nt) * math.prod(tile)
+    updates = float(n) * (2 * dom.Hs + 1) ** 2 * (2 * dom.Ht + 1)
+    prices = {"pb": updates * hw.pb_update_s,
+              "tiled": copies * hw.tile_copy_s + padded * hw.tile_voxel_s}
+    if jax.default_backend() != "tpu":
+        return "pb", prices
+    return min(prices, key=prices.get), prices

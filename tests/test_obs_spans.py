@@ -29,7 +29,8 @@ TREES = textwrap.dedent(
                           axis_types=(AxisType.Auto,) * 2)
     mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                           axis_types=(AxisType.Auto,) * 3)
-    calls = {"pb": dict(), "auto": dict(mesh=mesh2)}
+    calls = {"pb": dict(), "pb_forced": dict(use_tiled_kernel=False),
+             "auto": dict(mesh=mesh2)}
     for s in ("dr", "dd", "pd", "pd_xt", "dd_lpt"):
         calls[s] = dict(mesh=mesh2, strategy=s)
     for s in ("hybrid", "pd_xyt"):
@@ -66,14 +67,15 @@ def _children(spans, parent_id):
             if s["parent"] == parent_id]
 
 
-@pytest.mark.parametrize("case", ("pb", "auto") + STRATEGIES)
+@pytest.mark.parametrize("case", ("pb", "pb_forced", "auto") + STRATEGIES)
 def test_build_span_tree(trees, case):
     spans = trees[case]
     by_id = {s["id"]: s for s in spans}
     (root,) = [s for s in spans if s["parent"] is None]
     assert root["name"] == "stkde"
     s = root["attrs"]["path"]
-    assert s == case or (case == "auto" and s in STRATEGIES)
+    assert s == {"pb_forced": "pb"}.get(case, case) or (
+        case == "auto" and s in STRATEGIES)
     assert root["attrs"]["n"] == 1500
     assert root["attrs"]["voxels"] == 48 * 40 * 20
 
@@ -81,7 +83,8 @@ def test_build_span_tree(trees, case):
     for sp in spans:
         assert sp is root or _ancestors(by_id, sp)[-1] == root["id"]
 
-    plan = ["stkde.api.plan"] if case == "auto" else []
+    # a planner runs where the caller left the choice to the program
+    plan = ["stkde.api.plan"] if case in ("pb", "auto") else []
     assert _children(spans, root["id"]) == (
         ["stkde.api.validate"] + plan
         + [f"stkde.{s}", "stkde.api.wait", "stkde.api.check_finite"])
@@ -101,11 +104,24 @@ def test_build_span_tree(trees, case):
     if case == "auto":
         (pl,) = [sp for sp in spans if sp["name"] == "stkde.api.plan"]
         assert "bucketing.home" in _children(spans, pl["id"])
+    # the single-device planner prices both paths from the call's shape
+    # alone: no bucketing under its span, and its prices on the root
+    priced = {"priced_pb_s", "priced_tiled_s"}
+    if case == "pb":
+        (pl,) = [sp for sp in spans if sp["name"] == "stkde.api.plan"]
+        assert _children(spans, pl["id"]) == []
+        assert priced <= set(root["attrs"])
+        assert root["attrs"]["priced_pb_s"] > 0
+    else:
+        assert not priced & set(root["attrs"])
     (check,) = [sp for sp in spans if sp["name"] == "stkde.api.check_finite"]
     assert check["attrs"]["bytes"] == 48 * 40 * 20 * 4
-    # a first call compiles inside its dispatch phase
+    # a first call compiles inside its dispatch phase (pb_forced repeats
+    # the pb call, which compiled already)
     (disp,) = [sp for sp in spans if sp["name"] == f"stkde.{s}.dispatch"]
-    assert disp["attrs"]["compiles"] >= 1 and disp["attrs"]["compile_s"] > 0
+    if case != "pb_forced":
+        assert disp["attrs"]["compiles"] >= 1
+        assert disp["attrs"]["compile_s"] > 0
 
 
 def test_dispatch_counts_compiles_of_the_first_call_only():
