@@ -9,15 +9,24 @@ the configuration in ``bench/configs/<config>.json``, the traffic mix in
 The window is a closed loop with one caller: whole builds through the
 user's entry, ``repro.core.api.stkde(points, dom[, mesh=mesh])`` with
 every option at its default, each ended by ``block_until_ready``, back to
-back until ``--seconds`` have passed; the build in flight at the deadline
-finishes and counts. ``build_s`` is the window's elapsed time over the
-builds it completed.
+back until ``--seconds`` have passed and the cell's ``min_builds`` (in its
+workloads file, 1 where it gives none) have run; the build in flight at
+the deadline finishes and counts. ``build_s`` is the window's elapsed time
+over the builds it completed.
+
+The window holds one grid at a time, as a user holds the one on screen:
+each build drops the grid the last one returned, and has JAX free it,
+before it starts, inside the timed ``bench.build`` annotation, so freeing
+it stays in the window and out of the program's spans. Two grids held at
+once would cap a cell at half a chip's memory. The check reads the grid of
+the window's last build, and only that one.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import math
 import os
 import pathlib
 import re
@@ -60,6 +69,7 @@ class Cell:
     cfg: dict
     traffic: dict
     check: dict          # {number: {"limit": .., ...}}
+    min_builds: int      # the window runs at least this many builds
     end_to_end: List[dict]
     per_layer: List[dict]
 
@@ -78,11 +88,13 @@ def find_cell(root: pathlib.Path, name: str) -> Cell:
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     per_layer = [m for m in bench["per_layer"] if name in m["workloads"]]
+    workload = load_json(d / "workloads" / f"{name}.json")
     return Cell(
         name=name, entry=entry,
         cfg=load_json(d / "configs" / f"{entry['config']}.json"),
         traffic=load_json(d / "traffic" / f"{entry['traffic']}.json"),
-        check=load_json(d / "workloads" / f"{name}.json")["check"],
+        check=workload["check"],
+        min_builds=int(workload.get("min_builds", 1)),
         end_to_end=e2e, per_layer=per_layer)
 
 
@@ -172,6 +184,19 @@ def sample_and_reference(cfg: dict, points, grid, seed: int):
     return voxels, got, reference.vb_reference(points, cfg, voxels)
 
 
+def collect_dropped() -> None:
+    """Run now the release that JAX defers for arrays let go of.
+
+    A grid's host copy, cached on it by the output check, and its device
+    buffers go at JAX's next call after the last reference is dropped, and
+    that call is the next build's first program span (the points' transfer
+    in ``stkde.<s>.bucket``). Collecting here keeps the cost in the
+    window but out of the program's spans."""
+    from jaxlib import _jax
+
+    _jax.collect_garbage()
+
+
 def program_path(spans: List[ProgramSpan], mesh) -> str:
     """The strategy the program ran, by its ``stkde.<strategy>`` span."""
     names = sorted({s.name.split(".")[1] for s in spans
@@ -229,11 +254,15 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
         events.append((event, secs))
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    build_seconds: List[float] = []
     t0 = time.perf_counter()
     deadline = t0 + seconds
     while True:
         before = fallbacks.value
+        t_build = time.perf_counter()
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
+            grid = None       # the last grid goes before the next is built
+            collect_dropped()
             try:
                 grid = jax.block_until_ready(build())
             except Exception as e:  # a failed build is counted, not fatal
@@ -243,8 +272,11 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
             else:
                 if fallbacks.value != before:
                     failed += 1
+                    grid = None   # not the timed path's grid
         attempted += 1
-        if time.perf_counter() >= deadline:
+        now = time.perf_counter()
+        build_seconds.append(now - t_build)
+        if now >= deadline and attempted >= cell.min_builds:
             break
     window_s = time.perf_counter() - t0
     jax.monitoring.unregister_event_duration_listener(on_duration)
@@ -267,11 +299,15 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
     peak_bytes = [int(s.get("peak_bytes_in_use", 0)) for s in stats]
 
     # the check, on the grid the window's last build returned; a build
-    # that raised or fell back returned no grid to compare, so any such
-    # build makes the run not correct
-    voxels, got, want = sample_and_reference(cfg, points, grid, seed)
-    del grid
-    checks = {"max_err_rel": {"value": reference.max_err_rel(got, want),
+    # that raised or fell back returned no grid, so any such build makes
+    # the run not correct, and where the last one did, no grid is compared
+    if grid is None:
+        err, n_voxels = math.inf, 0
+    else:
+        voxels, got, want = sample_and_reference(cfg, points, grid, seed)
+        del grid
+        err, n_voxels = reference.max_err_rel(got, want), len(voxels)
+    checks = {"max_err_rel": {"value": err,
                               "limit": cell.check["max_err_rel"]["limit"]},
               "failed_builds": {"value": failed, "limit": 0}}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
@@ -283,11 +319,15 @@ def run_cell(root: pathlib.Path, cell_name: str, seed: int, seconds: float,
         "builds": attempted, "failed": failed, "errors": errors,
         "fallbacks": fallbacks.value,
         "nonfinite": obs.counter("resilience.nonfinite").value,
+        # one-device builds by the path the program took, the warm one too
+        "path_counters": {p: obs.counter(f"stkde.path.{p}").value
+                          for p in ("pb", "tiled")},
         "compiles_in_window": compiles,
         "cache_retrievals_in_window": sum(
             1 for e, _ in events if e == CACHE_RETRIEVAL),
         "peak_hbm_bytes_per_device": peak_bytes,
-        "voxels_compared": int(len(voxels)),
+        "voxels_compared": n_voxels,
+        "build_seconds": build_seconds,
     }
 
     flops = work.build_flops(len(points), cfg["Hs"], cfg["Ht"])

@@ -6,6 +6,7 @@ have: the grid returned unchanged from its initial zeros; half of the
 events left out, with the density normalised over the rest; the exchange
 between chips left out; one answer altered where it is produced.
 """
+import io
 import json
 import os
 import pathlib
@@ -85,12 +86,77 @@ def test_a_build_that_raises_counts_as_failed(tree):
 
     out = run(tree, "tiny.build", raises)
     assert out["failed"] == out["attempted"] >= 1
-    # the grid of the warm build is still sound, but no window build
-    # returned one: the run is not correct
-    assert out["check"]["max_err_rel"]["value"] <= (
-        out["check"]["max_err_rel"]["limit"])
+    # no window build returned a grid, so none is compared: not the warm
+    # build's, which was sound; the run is not correct
+    assert out["check"]["max_err_rel"]["value"] == np.inf
     assert out["check"]["failed_builds"]["value"] == out["failed"]
     assert not out["correct"]
+
+
+def test_a_build_starts_only_once_the_last_grid_is_dropped(tmp_path):
+    import gc
+    import weakref
+
+    held, returned = [], []
+
+    def one_grid(points, dom, mesh):
+        good = harness.stkde_build(points, dom, mesh)
+
+        def build():
+            gc.collect()    # what stays now is referenced, not garbage
+            if returned and returned[-1]() is not None:
+                held.append(len(returned))
+            grid = good()
+            returned.append(weakref.ref(grid))
+            return grid
+        return build
+
+    out = run(make_tree(tmp_path, min_builds=3), "tiny.build", one_grid)
+    assert out["correct"] and out["attempted"] >= 3
+    assert len(returned) == out["attempted"] + 1   # the warm build's too
+    assert held == []
+
+
+def test_the_dropped_grid_is_freed_inside_each_window_build(tmp_path,
+                                                           monkeypatch):
+    order = []
+    harness.collect_dropped()          # JAX's own collection runs here
+    monkeypatch.setattr(harness, "collect_dropped",
+                        lambda: order.append("free"))
+
+    def counted(points, dom, mesh):
+        good = harness.stkde_build(points, dom, mesh)
+
+        def build():
+            order.append("build")
+            return good()
+        return build
+
+    out = run(make_tree(tmp_path, min_builds=3), "tiny.build", counted)
+    assert out["correct"] and out["attempted"] >= 3
+    # the warm build, then before each window build the last grid's release
+    assert order == ["build"] + ["free", "build"] * out["attempted"]
+
+
+def test_the_window_runs_at_least_the_cells_min_builds(tmp_path):
+    root = make_tree(tmp_path, min_builds=4)
+    assert harness.find_cell(root, "tiny.build").min_builds == 4
+    from repro import obs
+    before = {p: obs.counter(f"stkde.path.{p}").value
+              for p in ("pb", "tiled")}
+    info = io.StringIO()
+    out = harness.run_cell(root, "tiny.build", 17, 0.0, False,
+                           time.perf_counter(), require_tpu=False,
+                           info_out=info)
+    assert out["correct"] and out["attempted"] == 4
+    run_info = json.loads(info.getvalue())["run_info"]
+    seconds = run_info["build_seconds"]
+    assert len(seconds) == 4 and all(s > 0 for s in seconds)
+    # off the TPU every one-device build, the warm one too, takes the
+    # scatter; the counters live as long as the process
+    took = {p: run_info["path_counters"][p] - before[p] for p in before}
+    assert took == {"pb": 5, "tiled": 0}
+    assert harness.find_cell(REPO, "flu_mr_hb_x4.build").min_builds == 1
 
 
 def cell_limit(cell):

@@ -29,6 +29,22 @@ def test_flu_mr_hb_on_four_chips_is_bound_by_bytes():
     assert t == pytest.approx(0.347e-3, rel=0.01)
 
 
+def test_flu_mr_hb_on_one_chip_is_bound_by_bytes():
+    n, grid, Hs, Ht = 31478, (233, 615, 1985), 4, 7
+    t, bound = work.least_time(work.build_flops(n, Hs, Ht),
+                               work.build_bytes(n, grid), V5E, 1)
+    assert bound == "memory"
+    assert t == pytest.approx(1.389e-3, rel=0.01)
+
+
+def test_pollenus_hr_lb_on_four_chips_is_a_quarter_of_one():
+    n, grid, Hs, Ht = 588189, (651, 301, 84), 10, 3
+    t, bound = work.least_time(work.build_flops(n, Hs, Ht),
+                               work.build_bytes(n, grid), V5E, 4)
+    assert bound == "memory"
+    assert t == pytest.approx(89e-6 / 4, rel=0.01)
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(ValueError, match="no peaks"):
         work.peaks_for("TPU v99")

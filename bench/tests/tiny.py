@@ -19,9 +19,11 @@ TINY_X4 = dict(TINY, name="tiny_x4", chips=4,
                mesh={"shape": [2, 2], "axes": ["data", "model"]})
 
 
-def make_tree(root: pathlib.Path, limit: float = 1e-4) -> pathlib.Path:
+def make_tree(root: pathlib.Path, limit: float = 1e-4,
+              min_builds: int = 1) -> pathlib.Path:
     """Write BENCHMARK.json and bench/ under ``root`` for the two tiny
-    cells ``tiny.build`` (one device) and ``tiny_x4.build`` (2x2 mesh)."""
+    cells ``tiny.build`` (one device) and ``tiny_x4.build`` (2x2 mesh),
+    each held to ``limit`` and running at least ``min_builds`` builds."""
     d = root / "bench"
     for sub in ("configs", "traffic", "workloads", "metrics"):
         (d / sub).mkdir(parents=True, exist_ok=True)
@@ -34,7 +36,8 @@ def make_tree(root: pathlib.Path, limit: float = 1e-4) -> pathlib.Path:
         (d / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
         cell = f"{cfg['name']}.build"
         (d / "workloads" / f"{cell}.json").write_text(json.dumps(
-            {"check": {"max_err_rel": {"limit": limit}}}))
+            {"check": {"max_err_rel": {"limit": limit}},
+             "min_builds": min_builds}))
         cells.append({"name": cell, "config": cfg["name"],
                       "traffic": "build", "chips": cfg["chips"],
                       "why": "test"})
