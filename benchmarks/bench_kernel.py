@@ -4,10 +4,10 @@ kernel's structural cost model.
 The Pallas kernel itself does not run here: it compiles only for the TPU,
 where ``chip_smoke.py`` runs it. What this section times is (a) the
 *scatter* path vs the *dense tile* oracle (``kernels.ref``, the same
-contraction in plain jnp) on the local backend — the structural advantage
-that motivates the TPU kernel — and (b) the kernel's analytic MXU
-utilisation per tile configuration (the numbers that justify the
-default_tile choice in kernels/ops.py).
+contraction in plain jnp, over the same panel-packed points) on the local
+backend — the structural advantage that motivates the TPU kernel — and (b)
+the kernel's analytic MXU utilisation per tile and panel size (the numbers
+that justify the default_tile choice in kernels/ops.py).
 """
 from __future__ import annotations
 
@@ -20,10 +20,11 @@ from repro.kernels import stkde_tiled, default_tile
 from repro.obs import timeit
 
 
-def tile_gemm_stats(dom: Domain, tile, cap: int) -> Dict:
-    """Structural analysis of one tile GEMM (V_s x P) @ (P x V_t)."""
+def tile_gemm_stats(dom: Domain, tile, panel: int) -> Dict:
+    """Structural analysis of one grid step's GEMM (V_s x P) @ (P x V_t),
+    P the points of one panel."""
     bx, by, bt = tile
-    m, k, n = bx * by, cap, bt
+    m, k, n = bx * by, panel, bt
     flops = 2 * m * k * n
     # bytes: Ks panel + Kt panel + accumulator (VMEM-resident)
     vmem = 4 * (k * m + k * n + m * n)
@@ -53,9 +54,9 @@ def run(quick=False) -> List[Dict]:
         "note": "dense tile path = structure the TPU kernel exploits",
     })
     print(f"  scatter={t_scatter:.4f}s tiled(dense jnp)={t_tiled_ref:.4f}s")
-    for tile, cap in (((8, 8, 8), 128), ((16, 16, 8), 256),
-                      ((32, 32, 16), 512), ((32, 32, 8), 1024)):
-        s = tile_gemm_stats(dom, tile, cap)
+    for tile, panel in (((8, 8, 8), 128), ((16, 16, 8), 256),
+                        ((32, 32, 16), 512), ((32, 32, 8), 1024)):
+        s = tile_gemm_stats(dom, tile, panel)
         rows.append({"bench": "tile_gemm_structure", **s})
         print(f"  tile {s['tile']}: {s['gemm']} MXU fill {s['mxu_fill']} "
               f"AI {s['arith_intensity']}")

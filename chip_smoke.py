@@ -129,10 +129,11 @@ def kernel_is_compiled(pts, dom) -> bool:
     from repro.kernels import tiled_inputs
     from repro.kernels.stkde_tile import stkde_tiles_pallas
 
-    lanes, tile, chunk = tiled_inputs(pts, dom)
-    spec = jax.ShapeDtypeStruct(lanes.shape, jnp.float32)
-    text = stkde_tiles_pallas.lower(spec, dom, tile, len(pts),
-                                    chunk).compile().as_text()
+    lanes, tile_of, tile = tiled_inputs(pts, dom)
+    specs = (jax.ShapeDtypeStruct(lanes.shape, jnp.float32),
+             jax.ShapeDtypeStruct(tile_of.shape, jnp.int32))
+    text = stkde_tiles_pallas.lower(*specs, dom, tile,
+                                    len(pts)).compile().as_text()
     return "tpu_custom_call" in text
 
 

@@ -1,8 +1,17 @@
 """Point -> tile bucketing (host-side data preparation).
 
-The TPU-native STKDE paths (Pallas tile kernel, DD/PD shard_map strategies,
-VB-DEC) all consume *capacity-padded dense buckets*: a (ntx, nty, ntt, cap, 3)
-array of points plus a validity mask. Scatter becomes dense per-tile compute.
+Two layouts, one per consumer:
+
+  * *capacity-padded dense buckets* (``Buckets``) for the mesh strategies
+    (DD/PD ``shard_map``) and VB-DEC: a (ntx, nty, ntt, cap, 3) array of
+    points plus a validity mask, every tile padded to the hottest tile's
+    load, because a ``shard_map`` wants one rectangular per-device capacity
+    (a compiled shape);
+  * *panels* (``bucket_points_panels``) for the single-device Pallas tile
+    kernel: each tile's point copies packed into whole panels of ``chunk``
+    slots, tiles back to back, in one (3, npanels * chunk) array, with an
+    (npanels,) table of each panel's tile. A tile costs the panels its own
+    load needs, not the hottest tile's.
 
 Two bucketing modes:
   * ``home``    — each point appears exactly once, in the tile containing its
@@ -11,6 +20,8 @@ Two bucketing modes:
                   bounding box intersects (DD-style replication; makes each
                   tile self-contained at the cost of cut-cylinder work
                   overhead — the exact overhead the paper measures in Fig. 9).
+                  Both layouts enumerate these copies the same way
+                  (``_overlap_copies``).
 
 This preparation is host-side numpy by design: in production it runs in the
 per-host data pipeline (like tokenization), not on the accelerator.
@@ -30,6 +41,10 @@ from .geometry import Domain
 # Coordinate of an empty or padding bucket slot: far outside every domain,
 # where every kernel is zero, so parked slots contribute nothing.
 PARK = -1e8
+
+# The panel layout's panel count is rounded up to a multiple of this, so
+# that event sets of about the same load share one compiled kernel shape.
+PANEL_STEP = 256
 
 
 @dataclasses.dataclass
@@ -163,12 +178,70 @@ def bucket_points_overlap(
     nt = num_tiles(dom, tile)
     with obs_trace.span("bucketing.overlap", n=n,
                         tiles=f"{nt[0]}x{nt[1]}x{nt[2]}") as sp:
-        b = _bucket_overlap(pts, dom, tile, nt, cap, n)
+        ids, src = _overlap_copies(pts, dom, tile, nt)
+        b = _densify(ids, pts[src], nt, cap, n, tile, "overlap")
         sp.set(cap=b.cap, replication=round(b.replication_factor, 3))
         return b
 
 
-def _bucket_overlap(pts, dom, tile, nt, cap, n) -> Buckets:
+def bucket_points_panels(
+    pts: np.ndarray,
+    dom: Domain,
+    tile: Tuple[int, int, int],
+    chunk: int,
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Overlap copies packed into panels of ``chunk`` slots:
+    ``(lanes, tile_of)``.
+
+    ``lanes`` (3, npanels * chunk) float32 holds rows x, y, t; ``tile_of``
+    (npanels,) int32 the flat tile id ``(tx * nty + ty) * ntt + tt`` of
+    each panel. Tile ``f`` gets ``max(1, ceil(count_f / chunk))``
+    consecutive panels in tile order, so every tile has at least one (a
+    tile with no copies gets one of parked points) and each tile's panels
+    form one run. ``npanels`` is rounded up to a multiple of
+    ``PANEL_STEP``; the extra panels belong to the last tile. Unused slots
+    hold ``PARK``. Within a tile the copies keep the order of the dense
+    buckets, so panel ``k`` of a tile holds what that bucket's slots
+    ``[k * chunk, (k + 1) * chunk)`` hold.
+    """
+    pts = np.asarray(pts, dtype=np.float32)
+    n = len(pts)
+    nt = num_tiles(dom, tile)
+    ntiles = nt[0] * nt[1] * nt[2]
+    with obs_trace.span("bucketing.overlap", n=n,
+                        tiles=f"{nt[0]}x{nt[1]}x{nt[2]}") as sp:
+        ids, src = _overlap_copies(pts, dom, tile, nt)
+        counts = np.bincount(ids, minlength=ntiles)
+        per_tile = np.maximum(1, -(-counts // chunk))
+        npanels = round_up(int(per_tile.sum()), PANEL_STEP)
+        per_tile[-1] += npanels - per_tile.sum()
+        tile_of = np.repeat(np.arange(ntiles, dtype=np.int32), per_tile)
+        # slot of each copy: its tile's first slot plus its rank in the tile;
+        # a 16-bit key takes NumPy's radix sort, several times faster here
+        key = ids.astype(np.uint16) if ntiles <= 1 << 16 else ids
+        order = np.argsort(key, kind="stable")
+        first_slot = np.zeros(ntiles, dtype=np.int64)
+        np.cumsum(per_tile[:-1] * chunk, out=first_slot[1:])
+        first_copy = np.zeros(ntiles, dtype=np.int64)
+        np.cumsum(counts[:-1], out=first_copy[1:])
+        sorted_ids = ids[order]
+        slot = np.arange(len(ids)) + (first_slot - first_copy)[sorted_ids]
+        lanes = np.full((3, npanels * chunk), PARK, dtype=np.float32)
+        lanes[:, slot] = pts[src[order]].T
+        sp.set(cap=lanes.shape[1] / ntiles, panels=npanels,
+               replication=round(len(ids) / max(1, n), 3))
+        return lanes, tile_of
+
+
+def _overlap_copies(
+    pts: np.ndarray,
+    dom: Domain,
+    tile: Tuple[int, int, int],
+    nt: Tuple[int, int, int],
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Every (point copy -> tile) of overlap bucketing: ``(ids, src)``, the
+    flat tile id of each copy and the index of its point, point-major."""
+    n = len(pts)
     vox = _point_voxels_np(pts, dom)
     lo = np.empty((n, 3), dtype=np.int64)
     hi = np.empty((n, 3), dtype=np.int64)
@@ -193,5 +266,5 @@ def _bucket_overlap(pts, dom, tile, nt, cap, n) -> Buckets:
     flat = (tids[..., 0] * nt[1] + tids[..., 1]) * nt[2] + tids[..., 2]
     sel = ok.reshape(-1)
     ids = flat.reshape(-1)[sel]
-    pts_rep = np.broadcast_to(pts[:, None, :], tids.shape).reshape(-1, 3)[sel]
-    return _densify(ids, pts_rep, nt, cap, n, tile, "overlap")
+    src = np.nonzero(sel)[0] // len(offs)
+    return ids, src

@@ -13,20 +13,26 @@ i.e. a GEMM contracting over the *point* dimension, executed on the MXU
 instead of a scalar scatter loop. Layout (Mosaic's (8, 128) block rule and
 the VMEM limit hold at the paper's Table 2 sizes):
 
-  * candidate points arrive pre-bucketed per tile (host-side, DD-style
-    overlap bucketing — ``core/bucketing.py``) with the points on the
-    lanes: a ``(3, chunk)`` block of x/y/t rows per grid step. Invalid
-    slots are parked far outside the domain, where every kernel is zero,
-    so no validity mask reaches the kernel;
-  * the point chunks stream through the innermost ``"arbitrary"`` grid
-    axis, so VMEM holds one chunk whatever the bucket capacity is;
-  * the output block ``(bt, bx*by)`` stays resident across that axis and
-    accumulates (the paper's DD "cache fitting" insight, made explicit);
-    the output is ``(ntx, nty, T, bx*by)``, reassembled into ``(X, Y, T)``
-    outside the kernel.
+  * candidate points arrive packed into panels (host-side, DD-style
+    overlap bucketing — ``core/bucketing.py::bucket_points_panels``): one
+    ``(3, npanels * chunk)`` array with the points on the lanes, each
+    tile's copies in whole panels of ``chunk`` slots, tiles back to back.
+    A ``(3, chunk)`` block of x/y/t rows is one grid step. Unused slots
+    are parked far outside the domain, where every kernel is zero, so no
+    validity mask reaches the kernel;
+  * an ``(npanels,)`` table gives each panel's flat tile id. It is
+    scalar-prefetched, so the output block's index map reads it: the
+    grid runs over the panels alone, one step per panel, and a tile costs
+    the panels its own load needs;
+  * each tile's panels are one run of consecutive steps, so the tile's
+    output block ``(bt, bx*by)`` stays resident across them and
+    accumulates (the paper's DD "cache fitting" insight, made explicit),
+    is zeroed on the run's first panel and written back once; the output
+    is ``(ntiles, bt, bx*by)``, the memory order of ``(ntx, nty, T,
+    bx*by)``, reassembled into ``(X, Y, T)`` outside the kernel.
 
-Grid = (ntx, nty, ntt, nchunks); the three tile axes are embarrassingly
-parallel.
+Grid = (npanels,), ``"arbitrary"``: consecutive panels of one tile add
+into one block.
 """
 from __future__ import annotations
 
@@ -35,34 +41,39 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bucketing import PARK
+from repro.core.bucketing import num_tiles
 from repro.core.geometry import Domain
 from repro.core import kernels_math as km
 
 MODES = ("interpret", "compiled")
-CHUNK = 512   # candidate points per grid step (lanes of the point block)
+CHUNK = 512   # slots of a panel: the points of one grid step (lanes)
 
 
-def _kernel(pts_ref, out_ref, *, dom: Domain, tile: Tuple[int, int, int],
+def _kernel(tile_of_ref, pts_ref, out_ref, *, dom: Domain,
+            tile: Tuple[int, int, int], nt: Tuple[int, int, int],
             norm: float, ks, kt):
-    """One (tile, chunk) grid step: ``out += Kt(chunk) @ Ks(chunk)ᵀ``.
+    """One panel's grid step: ``out += Kt(panel) @ Ks(panel)ᵀ``.
 
-    pts_ref: (3, chunk) rows x, y, t of this chunk's candidate points.
-    out_ref: (bt, bx*by) accumulator of this tile, column c = x * by + y.
+    tile_of_ref: (npanels,) flat tile id of every panel (scalar prefetch).
+    pts_ref: (3, chunk) rows x, y, t of this panel's candidate points.
+    out_ref: (bt, bx*by) accumulator of the panel's tile, column
+    c = x * by + y.
     """
     bx, by, bt = tile
+    _, nty, ntt = nt
+    p = pl.program_id(0)
+    f = tile_of_ref[p]
 
-    @pl.when(pl.program_id(3) == 0)
+    @pl.when((p == 0) | (tile_of_ref[jnp.maximum(p - 1, 0)] != f))
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x0 = (pl.program_id(0) * bx).astype(jnp.float32)
-    y0 = (pl.program_id(1) * by).astype(jnp.float32)
-    t0 = (pl.program_id(2) * bt).astype(jnp.float32)
+    x0 = (f // (nty * ntt) * bx).astype(jnp.float32)
+    y0 = (f // ntt % nty * by).astype(jnp.float32)
+    t0 = (f % ntt * bt).astype(jnp.float32)
     # (x, y) of every output column, from a 2-D iota (TPU needs >= 2-D)
     r = jax.lax.broadcasted_iota(jnp.int32, (bx * by, 1), 0).astype(
         jnp.float32)
@@ -86,75 +97,59 @@ def _kernel(pts_ref, out_ref, *, dom: Domain, tile: Tuple[int, int, int],
     )
 
 
-def lane_layout(points: np.ndarray, valid: np.ndarray,
-                chunk: int) -> Tuple[np.ndarray, int]:
-    """Kernel input from capacity-padded buckets, and the chunk to use.
-
-    ``points`` (ntx, nty, ntt, cap, 3) and ``valid`` (ntx, nty, ntt, cap)
-    become one (ntx, nty, ntt, 3, cap_p) array with the points on the
-    lanes and invalid slots parked at ``PARK``. A bucket smaller than
-    ``chunk`` becomes one chunk spanning the whole (8-aligned) bucket;
-    otherwise cap_p is padded to a multiple of ``chunk``. Either way the
-    point block's lane dimension is the whole array or ``chunk``, so a
-    multiple-of-128 ``chunk`` meets Mosaic's block rule. Built on the host:
-    on the device, a (..., cap, 3) array pads its 3 coordinates to 128
-    lanes.
-    """
-    cap = points.shape[3]
-    cap_p = -(-max(cap, 1) // 8) * 8
-    if cap_p > chunk:
-        cap_p = -(-cap // chunk) * chunk
-    else:
-        chunk = cap_p
-    out = np.full(points.shape[:3] + (3, cap_p), PARK, dtype=np.float32)
-    out[..., :cap] = np.where(valid[..., None], points, PARK).swapaxes(-1, -2)
-    return out, chunk
-
-
 @functools.partial(
     jax.jit,
-    static_argnames=("dom", "tile", "n_total", "chunk", "ks", "kt", "mode"),
+    static_argnames=("dom", "tile", "n_total", "ks", "kt", "mode"),
 )
 def stkde_tiles_pallas(
-    pts_lanes: jnp.ndarray,    # (ntx, nty, ntt, 3, cap_p) f32, lane_layout
+    lanes: jnp.ndarray,        # (3, npanels * chunk) f32, panel-packed
+    tile_of: jnp.ndarray,      # (npanels,) int32, each panel's flat tile
     dom: Domain,
     tile: Tuple[int, int, int],
     n_total: int,
-    chunk: int,
     ks: km.SpatialKernel = km.DEFAULT_KS,
     kt: km.TemporalKernel = km.DEFAULT_KT,
     mode: str = "compiled",
 ) -> jnp.ndarray:
-    """Padded density grid (ntx*bx, nty*by, ntt*bt) from bucketed points.
+    """Padded density grid (ntx*bx, nty*by, ntt*bt) from panel-packed
+    points (``bucketing.bucket_points_panels``).
 
-    ``mode="compiled"`` lowers through Mosaic, the TPU kernel compiler, and
-    fails on any other backend; ``mode="interpret"`` runs the kernel body
-    under the Pallas interpreter (any backend, slow) and is what CPU tests
-    ask for.
+    The panel size is ``lanes``' width over the panel count; a multiple of
+    128 meets Mosaic's block rule. ``mode="compiled"`` lowers through
+    Mosaic, the TPU kernel compiler, and fails on any other backend;
+    ``mode="interpret"`` runs the kernel body under the Pallas interpreter
+    (any backend, slow) and is what CPU tests ask for.
     """
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
-    ntx, nty, ntt, _, cap_p = pts_lanes.shape
+    (npanels,) = tile_of.shape
+    chunk = lanes.shape[1] // npanels
+    nt = num_tiles(dom, tile)
+    ntx, nty, ntt = nt
     bx, by, bt = tile
     kernel = functools.partial(
-        _kernel, dom=dom, tile=tile,
+        _kernel, dom=dom, tile=tile, nt=nt,
         norm=km.normalization(n_total, dom.hs, dom.ht), ks=ks, kt=kt)
     out = pl.pallas_call(
         kernel,
-        grid=(ntx, nty, ntt, cap_p // chunk),
-        in_specs=[pl.BlockSpec((None, None, None, 3, chunk),
-                               lambda i, j, k, c: (i, j, k, 0, c))],
-        out_specs=pl.BlockSpec((None, None, bt, bx * by),
-                               lambda i, j, k, c: (i, j, k, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntx, nty, ntt * bt, bx * by),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(npanels,),
+            in_specs=[pl.BlockSpec((3, chunk), lambda p, tile_of: (0, p))],
+            out_specs=pl.BlockSpec((None, bt, bx * by),
+                                   lambda p, tile_of: (tile_of[p], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((ntx * nty * ntt, bt, bx * by),
                                        jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=mode == "interpret",
         name="stkde_tile",
-    )(pts_lanes)
-    # (ntx, nty, T, bx*by) -> (X, Y, T); every intermediate keeps a minor
-    # dimension the TPU's (8, 128) tiled layout stores without padding
+    )(tile_of, lanes)
+    # (ntiles, bt, bx*by) is (ntx, nty, T, bx*by) in memory -> (X, Y, T);
+    # every intermediate keeps a minor dimension the TPU's (8, 128) tiled
+    # layout stores without padding
+    out = out.reshape(ntx, nty, ntt * bt, bx * by)
     out = jnp.swapaxes(out, 2, 3).reshape(ntx, nty, bx, by, ntt * bt)
     return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(
         ntx * bx, nty * by, ntt * bt)

@@ -26,7 +26,7 @@ from repro.kernels import default_tile
 from repro.kernels.stkde_tile import CHUNK, stkde_tiles_pallas
 
 HBM_BYTES = 16e9          # one v5e chip
-POLLEN_CAP = 90_064       # hottest 32x32x16 tile of PollenUS_Hr-Lb (seed 2)
+POLLEN_PANELS = 4608      # PollenUS_Hr-Lb's 512-slot panels, step-rounded
 
 
 @pytest.fixture(scope="module")
@@ -77,11 +77,12 @@ def test_tile_kernel_compiles_at_pollen_size(one_chip):
     tile = default_tile(dom)
     ntiles = bucketing.num_tiles(dom, tile)
     assert ntiles == (21, 10, 6)
-    cap_p = bucketing.round_up(POLLEN_CAP, CHUNK)
-    lanes = jax.ShapeDtypeStruct(ntiles + (3, cap_p), jnp.float32,
+    lanes = jax.ShapeDtypeStruct((3, POLLEN_PANELS * CHUNK), jnp.float32,
                                  sharding=one_chip)
-    compiled = stkde_tiles_pallas.lower(lanes, dom, tile, inst.n,
-                                        CHUNK).compile()
+    tile_of = jax.ShapeDtypeStruct((POLLEN_PANELS,), jnp.int32,
+                                   sharding=one_chip)
+    compiled = stkde_tiles_pallas.lower(lanes, tile_of, dom, tile,
+                                        inst.n).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
 

@@ -1,7 +1,10 @@
 """The span tree of one ``stkde()`` build: a root ``stkde``, the API phases,
 and every strategy's ``bucket`` / ``dispatch`` / ``reassemble`` phases."""
+import importlib.util
 import json
+import pathlib
 import textwrap
+import types
 
 import numpy as np
 import pytest
@@ -162,7 +165,7 @@ def test_rejected_input_closes_the_tree_at_validation():
 def test_tiled_kernel_opens_the_same_phases():
     dom = Domain(gx=24., gy=20., gt=10., sres=1., tres=1., hs=2., ht=1.)
     pts = clustered_events(200, dom, seed=3)
-    from repro.kernels import stkde_tiled
+    from repro.kernels import default_tile, stkde_tiled
 
     stkde_tiled(pts, dom, mode="interpret")
     spans = trace.get_tracer().spans()
@@ -174,3 +177,34 @@ def test_tiled_kernel_opens_the_same_phases():
         "stkde.tiled.reassemble"]
     (t,) = trace.get_tracer().spans("transfer.to_device")
     assert t.parent_id == kids[0].span_id and t.attrs["bytes"] > 0
+
+    # the overlap bucketing names the panel layout: ``cap`` is the slots
+    # laid out per tile, so the benchmark's fill reads copies over the
+    # slots the kernel computes on
+    from repro.core import bucketing
+    from repro.kernels.stkde_tile import CHUNK
+
+    (ov,) = trace.get_tracer().spans("bucketing.overlap")
+    assert ov.parent_id == kids[0].span_id
+    tile = default_tile(dom)
+    counts = bucketing.bucket_points_overlap(pts, dom, tile).counts
+    raw = int(np.maximum(1, -(-counts // CHUNK)).sum())
+    panels = bucketing.round_up(raw, bucketing.PANEL_STEP)
+    assert ov.attrs["panels"] == panels
+    assert ov.attrs["tiles"] == "x".join(map(str, counts.shape))
+    assert ov.attrs["cap"] == panels * CHUNK / counts.size
+    assert ov.attrs["n"] == 200
+    assert ov.attrs["replication"] == round(counts.sum() / 200, 3)
+    rec = types.SimpleNamespace(spans=[ov])
+    assert _bucket_fill()(rec) == pytest.approx(
+        100 * counts.sum() / (panels * CHUNK), rel=1e-3)
+
+
+def _bucket_fill():
+    """The benchmark's ``bucket_fill`` reader (bench/metrics/)."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "metrics" / "bucket_fill.py")
+    spec = importlib.util.spec_from_file_location("bucket_fill", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read

@@ -156,6 +156,31 @@ class TestGeometry:
 
 
 # ----------------------------------------------------------------- bucketing
+def _dense_overlap_before(pts, dom, tile, cap):
+    """Overlap bucketing as it was written before its copy enumeration was
+    shared with the panel layout: every (point, tile offset) as an
+    (n, S, 3) index array, masked, into ``_densify``."""
+    pts = np.asarray(pts, dtype=np.float32)
+    n = len(pts)
+    nt = bucketing.num_tiles(dom, tile)
+    vox = bucketing._point_voxels_np(pts, dom)
+    H = np.array([dom.Hs, dom.Hs, dom.Ht])
+    B, NT = np.array(tile), np.array(nt)
+    lo = np.clip((vox - H) // B, 0, NT - 1)
+    hi = np.clip((vox + H) // B, 0, NT - 1)
+    span = hi - lo + 1
+    smax = span.max(axis=0)
+    offs = np.stack(np.meshgrid(*(np.arange(m) for m in smax),
+                                indexing="ij"), axis=-1).reshape(-1, 3)
+    tids = lo[:, None, :] + offs[None, :, :]
+    ok = (offs[None, :, :] < span[:, None, :]).all(axis=-1)
+    flat = (tids[..., 0] * nt[1] + tids[..., 1]) * nt[2] + tids[..., 2]
+    sel = ok.reshape(-1)
+    ids = flat.reshape(-1)[sel]
+    pts_rep = np.broadcast_to(pts[:, None, :], tids.shape).reshape(-1, 3)[sel]
+    return bucketing._densify(ids, pts_rep, nt, cap, n, tile, "overlap")
+
+
 class TestBucketing:
     def test_home_counts_sum_to_n(self):
         dom = small_domain()
@@ -191,6 +216,28 @@ class TestBucketing:
                             k * tile[2] : (k + 1) * tile[2],
                         ] = True
         assert (g_full[~covered] == 0).all()
+
+    @pytest.mark.parametrize("grid,hs,ht,tile,cap", [
+        ((24, 18, 14), 3.0, 2.0, (8, 8, 8), None),
+        ((17, 19, 23), 2.0, 2.0, (8, 8, 16), None),   # tiles overhang
+        ((40, 40, 8), 5.0, 1.0, (40, 40, 8), 1024),   # one tile, given cap
+        ((48, 40, 20), 4.0, 3.0, (24, 20, 20), None),  # a 2x2 DD layout
+    ])
+    def test_overlap_buckets_unchanged_by_the_shared_enumeration(
+            self, grid, hs, ht, tile, cap):
+        """The mesh strategies' dense overlap buckets are bit-identical to
+        those of the enumeration before the panel layout shared it."""
+        dom = Domain(gx=float(grid[0]), gy=float(grid[1]),
+                     gt=float(grid[2]), sres=1.0, tres=1.0, hs=hs, ht=ht)
+        pts = clustered_events(700, dom, seed=sum(grid))
+        got = bucketing.bucket_points_overlap(pts, dom, tile, cap=cap)
+        want = _dense_overlap_before(pts, dom, tile, cap)
+        for field in ("points", "valid", "counts"):
+            a, b = getattr(got, field), getattr(want, field)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+        assert got.cap == want.cap
+        assert got.replication_factor == want.replication_factor
 
     def test_capacity_overflow_raises(self):
         dom = small_domain()

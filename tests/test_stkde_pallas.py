@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import Domain, pb, clustered_events, bucketing
 from repro.core import kernels_math as km
-from repro.kernels import stkde_tiled
+from repro.kernels import stkde_tiled, tiled_inputs
 from repro.kernels.ref import stkde_tiles_ref
 from repro.kernels.stkde_tile import stkde_tiles_pallas
 
@@ -40,13 +40,119 @@ def test_kernel_vs_scatter_sweep(grid, hs, ht, tile):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-8)
 
 
-@pytest.mark.parametrize("chunk", [8, 64, 256])
+@pytest.mark.parametrize("chunk", [8, 64, 128, 512])
 def test_kernel_chunk_sizes(chunk):
+    """Every panel size gives the oracle's grid and the scatter's."""
     dom = Domain(gx=32, gy=32, gt=16, sres=1.0, tres=1.0, hs=3.0, ht=2.0)
     pts = _make(dom, 600, seed=11)
-    want = np.asarray(stkde_tiled(pts, dom, use_ref=True))
+    want = np.asarray(stkde_tiled(pts, dom, chunk=chunk, use_ref=True))
     got = np.asarray(stkde_tiled(pts, dom, chunk=chunk, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-9)
+    np.testing.assert_allclose(got, np.asarray(pb(pts, dom)),
+                               rtol=1e-5, atol=1e-8)
+
+
+# ---------------------------------------------------------- packed layout
+def _packed(pts, dom, tile, chunk):
+    """The kernel's padded grid and the oracle's from one packing."""
+    lanes, tile_of, tile = tiled_inputs(pts, dom, tile, chunk)
+    got = stkde_tiles_pallas(jnp.asarray(lanes), jnp.asarray(tile_of), dom,
+                             tile, len(pts), mode="interpret")
+    ref = stkde_tiles_ref(jnp.asarray(lanes), jnp.asarray(tile_of), dom,
+                          tile, len(pts))
+    return lanes, tile_of, np.asarray(got), np.asarray(ref)
+
+
+def _panels_per_tile(pts, dom, tile, chunk):
+    counts = bucketing.bucket_points_overlap(pts, dom, tile).counts
+    return counts.reshape(-1), np.maximum(1, -(-counts.reshape(-1) // chunk))
+
+
+def test_packed_empty_tiles_get_one_parked_panel_and_are_zero():
+    """Tiles no cylinder reaches still get a panel, and come out exactly
+    zero."""
+    dom = Domain(gx=64, gy=64, gt=16, sres=1.0, tres=1.0, hs=2.0, ht=1.0)
+    tile = (8, 8, 8)
+    rng = np.random.default_rng(3)
+    pts = (2.0 + rng.random((80, 3)) * 10.0).astype(np.float32)
+    counts, _ = _panels_per_tile(pts, dom, tile, 128)
+    assert (counts == 0).sum() > 100
+    lanes, tile_of, got, ref = _packed(pts, dom, tile, 128)
+    assert set(tile_of.tolist()) == set(range(counts.size))
+    g = got[: dom.Gx, : dom.Gy, : dom.Gt]
+    assert (g[24:, :, :] == 0.0).all() and (g[:, 24:, :] == 0.0).all()
+    assert g.sum() > 0
+    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-9)
+    np.testing.assert_allclose(g, np.asarray(pb(pts, dom)),
+                               rtol=1e-5, atol=1e-8)
+
+
+def test_hot_tile_spans_many_panels_beside_partial_ones():
+    """One tile's copies run over many panels; its neighbours hold one
+    partial panel each; all of them accumulate the scatter's grid."""
+    dom = Domain(gx=32, gy=32, gt=8, sres=1.0, tres=1.0, hs=2.0, ht=1.0)
+    tile = (8, 8, 8)
+    rng = np.random.default_rng(5)
+    hot = 11.0 + rng.random((1500, 3)) * np.array([2.0, 2.0, 6.0])
+    cold = rng.random((40, 3)) * np.array([32.0, 32.0, 8.0])
+    pts = np.concatenate([hot, cold]).astype(np.float32)
+    counts, per_tile = _panels_per_tile(pts, dom, tile, 128)
+    assert per_tile.max() >= 12
+    assert ((counts > 0) & (counts < 128)).sum() >= 4
+    lanes, tile_of, got, ref = _packed(pts, dom, tile, 128)
+    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-9)
+    np.testing.assert_allclose(got[: dom.Gx, : dom.Gy, : dom.Gt],
+                               np.asarray(pb(pts, dom)), rtol=1e-5, atol=1e-8)
+
+
+def test_panel_count_rounding_shares_a_shape_and_changes_nothing():
+    """Event sets of near-equal load pack into one panel count, and the
+    parked panels the rounding adds leave the grid bit for bit."""
+    dom = Domain(gx=40, gy=32, gt=16, sres=1.0, tres=1.0, hs=3.0, ht=2.0)
+    tile, chunk = (8, 8, 8), 128
+    shapes = set()
+    for seed in (21, 22):
+        pts = _make(dom, 500, seed=seed)
+        lanes, tile_of, got, _ = _packed(pts, dom, tile, chunk)
+        shapes.add(lanes.shape)
+        _, per_tile = _panels_per_tile(pts, dom, tile, chunk)
+        raw = int(per_tile.sum())
+        assert len(tile_of) == bucketing.round_up(raw, bucketing.PANEL_STEP)
+        assert len(tile_of) > raw
+        assert (lanes[:, raw * chunk:] == bucketing.PARK).all()
+        assert (tile_of[raw:] == per_tile.size - 1).all()
+        bare = stkde_tiles_pallas(
+            jnp.asarray(lanes[:, : raw * chunk]), jnp.asarray(tile_of[:raw]),
+            dom, tile, len(pts), mode="interpret")
+        np.testing.assert_array_equal(got, np.asarray(bare))
+    assert len(shapes) == 1
+
+
+@pytest.mark.parametrize("grid,hs,ht,tile", TILE_CASES)
+def test_panel_table_is_contiguous_per_tile(grid, hs, ht, tile):
+    """Each tile owns one run of panels, in tile order, as many as its
+    load needs (at least one); the run holds its dense bucket's copies in
+    the bucket's order, then parked slots."""
+    dom = Domain(gx=float(grid[0]), gy=float(grid[1]), gt=float(grid[2]),
+                 sres=1.0, tres=1.0, hs=hs, ht=ht)
+    pts = _make(dom, 400, seed=sum(grid))
+    chunk = 16
+    lanes, tile_of = bucketing.bucket_points_panels(pts, dom, tile, chunk)
+    dense = bucketing.bucket_points_overlap(pts, dom, tile)
+    counts = dense.counts.reshape(-1)
+    per_tile = np.maximum(1, -(-counts // chunk))
+    assert tile_of.dtype == np.int32 and lanes.dtype == np.float32
+    assert lanes.shape == (3, len(tile_of) * chunk)
+    assert (np.diff(tile_of) >= 0).all()
+    runs = np.bincount(tile_of, minlength=counts.size)
+    np.testing.assert_array_equal(runs[:-1], per_tile[:-1])
+    assert runs[-1] >= per_tile[-1]
+    first = np.concatenate([[0], np.cumsum(runs)[:-1]]) * chunk
+    flat = dense.points.reshape(counts.size, dense.cap, 3)
+    for f, c in enumerate(counts):
+        run = lanes[:, first[f]: first[f] + runs[f] * chunk]
+        np.testing.assert_array_equal(run[:, :c].T, flat[f, :c])
+        assert (run[:, c:] == bucketing.PARK).all()
 
 
 def test_kernel_nonunit_resolution_and_origin():
