@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``examples/*.py``)
+Entry points (``chip_smoke.py``, ``examples/*.py``)
 call ``enable_compile_cache()`` once, before their first compile; nothing
 calls it at import. The cache directory is part of the cache's key, so it
 never moves between runs: ``JAX_COMPILATION_CACHE_DIR`` when that is set,

@@ -8,7 +8,6 @@ from .datasets import (
     STKDEInstance,
     INSTANCES,
     get_instance,
-    bench_suite,
     clustered_events,
 )
 # keep last: api pulls in resilience, which imports core.geometry above
@@ -30,6 +29,5 @@ __all__ = [
     "STKDEInstance",
     "INSTANCES",
     "get_instance",
-    "bench_suite",
     "clustered_events",
 ]

@@ -277,7 +277,7 @@ def _replan_after_loss(dom: Domain, n_total: int, mesh, axes, rep_axis):
     """Pick (mesh, strategy) for the chunks remaining after a device loss.
 
     Shrinks the mesh by one device and re-runs the parametric planner
-    with the calibrated hardware model; when no multi-device mesh
+    with the v5e hardware model; when no multi-device mesh
     survives, degrades to single-device local execution (strategy
     ``local``).
     """

@@ -152,7 +152,3 @@ INSTANCES: Dict[str, STKDEInstance] = {
 def get_instance(name: str) -> STKDEInstance:
     return INSTANCES[name]
 
-
-def bench_suite(max_voxels: int = 1_500_000, max_points: int = 20_000):
-    """Scaled-down versions of every instance, CPU-runnable."""
-    return {k: v.scaled(max_voxels, max_points) for k, v in INSTANCES.items()}

@@ -9,8 +9,8 @@ kernel evaluation is hoisted out of the cylinder loop:
   PB-BAR   hoists the temporal invariant Kt[T]
   PB-SYM   hoists both; cylinder work is a pure outer product Ks ⊗ Kt
 
-All variants produce identical grids; they exist separately so the Table-3
-benchmark reproduces the paper's flop-reduction story. The redundant work in
+All variants produce identical grids; they exist separately to reproduce the
+flop-reduction story of the paper's Table 3. The redundant work in
 PB / PB-DISK / PB-BAR is expressed through *materialized* broadcasts so XLA
 actually performs it.
 
@@ -175,48 +175,6 @@ def _pb_impl(
 
     grid, _ = jax.lax.scan(body, grid, (pts_b, vox_b))
     return grid[:gsz].reshape(dom.grid_shape)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("dom", "variant", "ks", "kt", "budget_elems",
-                     "n_total"),
-)
-def _pb_eval_impl(points, dom, variant, ks, kt, budget_elems,
-                  n_total=None):
-    """Kernel-evaluation phase only (no scatter): checksum of all cylinder
-    values. Times the compute phase the paper's Table 3 differentiates;
-    the scatter/accumulate phase is variant-independent (see benchmarks)."""
-    n = points.shape[0]
-    n_norm = n if n_total is None else n_total
-    B = _block_size(dom, budget_elems)
-    nblocks = -(-n // B)
-    pad = nblocks * B - n
-    pts = jnp.pad(points.astype(jnp.float32), ((0, pad), (0, 0)))
-    if pad:
-        pts = pts.at[n:, 0].set(jnp.float32(dom.ox - 1e8))
-    vox = dom.point_voxels_unclipped(pts)
-
-    def body(acc, blk):
-        p, v = blk
-        _, vals = _cylinder_values(p, v, dom, variant, ks, kt, n_norm)
-        return acc + vals.sum(), None
-
-    acc, _ = jax.lax.scan(
-        body, jnp.float32(0),
-        (pts.reshape(nblocks, B, 3), vox.reshape(nblocks, B, 3)),
-    )
-    return acc
-
-
-def pb_eval_only(points, dom: Domain, variant: str = "sym",
-                 ks: km.SpatialKernel = km.DEFAULT_KS,
-                 kt: km.TemporalKernel = km.DEFAULT_KT,
-                 budget_elems: int = 1 << 22):
-    if variant not in VARIANTS:
-        raise ValueError(f"variant must be one of {VARIANTS}")
-    return _pb_eval_impl(jnp.asarray(points), dom, variant, ks, kt,
-                         budget_elems)
 
 
 def pb(points, dom: Domain, variant: str = "sym",

@@ -18,10 +18,8 @@ default to TPU v5e; ``default_hw()`` picks them by the device JAX runs on.
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
-import os
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -48,120 +46,21 @@ class Hardware:
 
 V5E = Hardware()
 
-# Rough single-host CPU constants for reconciliation smoke runs (8 fake XLA
-# host devices share one socket, so per-"device" rates are fractions of the
-# socket). HOST_SEED is the uncalibrated starting point; HOST below folds in
-# the measured reconcile rows.
-HOST_SEED = Hardware(
-    peak_flops=5e10,     # per fake device, fp32 vector path
-    hbm_bw=4e9,          # DRAM bandwidth share per fake device
-    ici_bw=4e9,          # "collective" = memcpy through shared memory
-    hbm_bytes=4e9,
-    vpu_derate=1.0,      # scatter path on CPU is the same ALUs
-    mxu_derate=1.0,
-    pb_update_s=1.8e-8,  # XLA:CPU scatter, one process (PERF.md §6)
-    tile_copy_s=math.inf,    # no compiled tile kernel off the TPU
-    tile_voxel_s=math.inf,
-)
-
-# Calibrated against results/bench/reconcile.json (mesh 2x2x2, n=8000, all
-# seven probed strategies): XLA:CPU's scatter path dispatches per point,
-# nowhere near vector peak — peak_flops carries the geo-mean-fitted scatter
-# rate (dr/dd/pd/pd_xt/pd_xyt/hybrid compute rel-err lands within ~2x).
-# dd_lpt's separable tile contraction is a GEMM and runs ~15x faster than
-# the scatter strategies on the same cores, so its rate is carried
-# separately in mxu_derate (see estimate()'s rate_tile). Memory-bandwidth
-# (init) terms were already within ~2x and are left at their seed values,
-# as is ici_bw (the collective probes measure ~ms-scale comm on shared
-# memory, so a bandwidth "fit" is unidentifiable from these rows and would
-# distort choose()).
-HOST = dataclasses.replace(HOST_SEED, peak_flops=3.0e6, mxu_derate=15.5)
-
-
-def probed_strategies() -> Tuple[str, ...]:
-    """Strategy names with a phase-probe spec (``obs.reconcile.PROBED``).
-
-    Single source of truth for which rows calibration may trust — derived
-    from the probe registry so the two can never drift.
-    """
-    from repro.obs import reconcile
-
-    return tuple(reconcile.PROBED)
-
-
-# strategies whose compute runs on the tile-GEMM (einsum/MXU) path; every
-# other strategy is on the scatter (VPU) path — see estimate()
-TILE_PATH = ("dd_lpt",)
-
-
-def calibrate_host(rows, base: Hardware = HOST_SEED,
-                   strategies: Optional[Sequence[str]] = None) -> Hardware:
-    """Re-fit the host compute rates from reconcile rows.
-
-    ``rows`` is the ``rows`` list of a ``obs.reconcile`` report (or a path
-    to one): entries with ``term == "compute_s"`` and positive
-    predicted/measured values contribute ``measured / predicted`` ratios.
-    ``base.peak_flops`` (the Hardware that *produced* those predictions)
-    is divided by the geometric mean of the scatter-path strategies'
-    ratios; ``base.mxu_derate`` is re-fitted from the ``TILE_PATH``
-    strategies' ratios so the tile-GEMM rate tracks its own measurement.
-    Terms other than compute are left untouched — see the HOST comment
-    above.
-
-    ``strategies`` limits which rows contribute; it defaults to the probe
-    registry keys (``obs.reconcile.PROBED``) so rows from unknown or
-    retired strategies in an old report can't skew the fit.
-    """
-    if isinstance(rows, (str, os.PathLike)):
-        with open(rows) as f:
-            rows = json.load(f)
-    if isinstance(rows, dict):
-        rows = rows.get("rows", [])
-    if rows and isinstance(rows[0], dict) and "rows" in rows[0]:
-        # a reconcile.json file: list of per-run reports, each with rows
-        rows = [r for rep in rows for r in rep.get("rows", [])]
-    allowed = set(probed_strategies() if strategies is None else strategies)
-
-    def geomean_ratio(names):
-        ratios = [
-            r["measured_s"] / r["predicted_s"]
-            for r in rows
-            if r.get("term") == "compute_s"
-            and r.get("strategy") in names
-            and r.get("predicted_s", 0) > 0 and r.get("measured_s", 0) > 0
-        ]
-        if not ratios:
-            return None
-        return math.exp(sum(math.log(x) for x in ratios) / len(ratios))
-
-    g_scatter = geomean_ratio(allowed - set(TILE_PATH))
-    g_tile = geomean_ratio(allowed & set(TILE_PATH))
-    out = base
-    if g_scatter is not None:
-        out = dataclasses.replace(out, peak_flops=base.peak_flops / g_scatter)
-    if g_tile is not None:
-        # tile rate = peak_flops * mxu_derate must shrink by g_tile; the
-        # peak_flops change above is compensated inside the derate
-        scale = g_scatter if g_scatter is not None else 1.0
-        out = dataclasses.replace(
-            out, mxu_derate=base.mxu_derate * scale / g_tile)
-    return out
-
-
 # Peaks by ``device_kind``. v5e: Google Cloud documentation, "TPU v5e"
 # (197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s per chip).
 PEAKS: Dict[str, Hardware] = {"TPU v5 lite": V5E}
 
 
 def default_hw() -> Hardware:
-    """The Hardware model of JAX's first device: HOST on the CPU, the
-    ``PEAKS`` entry of its ``device_kind`` otherwise. A device kind with no
-    entry is an error, not a default."""
+    """The Hardware model of JAX's first device: the ``PEAKS`` entry of its
+    ``device_kind``. On the CPU it is ``V5E``, the chip the planner plans
+    for, so a CPU run plans exactly as the v5e would. An accelerator kind
+    with no entry is an error, not a default."""
     import jax
 
     dev = jax.devices()[0]
     if dev.platform == "cpu":
-        return HOST
+        return V5E
     try:
         return PEAKS[dev.device_kind]
     except KeyError:
@@ -183,7 +82,6 @@ def estimate(
     mesh_shape: Tuple[int, ...],
     loads: Optional[np.ndarray] = None,
     hw: Hardware = V5E,
-    use_mxu: bool = True,
 ) -> Dict[str, Dict[str, float]]:
     """Per-strategy cost breakdown in seconds. mesh_shape=(A, B) or (R, A, B)."""
     if len(mesh_shape) == 3:
@@ -199,12 +97,9 @@ def estimate(
     # Two compute paths with very different efficiency: the scatter-based
     # PB-SYM strategies (dr/dd/pd/pd_xt/pd_xyt/hybrid) run at the VPU
     # rate, while dd_lpt's separable tile contraction is a GEMM (MXU)
-    # workload. Pricing them with one shared rate hid a >10x compute
-    # misprediction for dd_lpt in the reconcile rows.
+    # workload.
     rate_scatter = hw.peak_flops * hw.vpu_derate
-    rate_tile = hw.peak_flops * (
-        hw.mxu_derate if use_mxu else hw.vpu_derate
-    )
+    rate_tile = hw.peak_flops * hw.mxu_derate
 
     # overlap replication factor (cut cylinders) for DD-style strategies
     tiles_per_dim_x = max(1.0, gx_loc / (2 * dom.Hs + 1))

@@ -130,25 +130,15 @@ def stkde_dr(
 
 
 def build_dr(dom: Domain, mesh: Mesh, axes, n: int,
-             ks=km.DEFAULT_KS, kt=km.DEFAULT_KT, collectives: bool = True):
-    """Jitted DR computation over pre-sharded points (dry-run lowerable).
-
-    ``collectives=False`` compiles the same per-device point work but skips
-    the all-reduce, returning the device-stacked partial grids — the
-    reconciliation probe for the planner's ``comm_s`` term.
-    """
+             ks=km.DEFAULT_KS, kt=km.DEFAULT_KT):
+    """Jitted DR computation over pre-sharded points (dry-run lowerable)."""
 
     def f(local):  # (npad/P, 3)
         g = _pb(local, dom, variant="sym", ks=ks, kt=kt, n_total=n)
-        if collectives:
-            return jax.lax.psum(g, axes)
-        return g[None]
+        return jax.lax.psum(g, axes)
 
-    out_specs = (
-        P(None, None, None) if collectives else P(axes, None, None, None)
-    )
     return jax.jit(shard_map(
-        f, mesh=mesh, in_specs=P(axes), out_specs=out_specs
+        f, mesh=mesh, in_specs=P(axes), out_specs=P(None, None, None)
     ))
 
 
@@ -310,9 +300,9 @@ def build_pd(dom: Domain, mesh: Mesh, axes, n: int,
 
     Input layout: (A, B, cap, 3) — or (R, A, B, cap, 3) with rep_axis for
     the hybrid/REP strategy. Dry-run lowerable with ShapeDtypeStructs.
-    ``collectives=False`` skips the halo ppermute folds (and rep psum) —
-    the reconciliation probe for the planner's ``comm_s`` term; the output
-    is then the unfolded interior (numerically incomplete by design).
+    ``collectives=False`` skips the halo ppermute folds (and rep psum):
+    the output is then the unfolded interior, a known-wrong grid that the
+    benchmark's fault tests feed to its correctness check.
     """
     ax, ay = axes
     A, B = _mesh_sizes(mesh, axes)
@@ -328,7 +318,7 @@ def build_pd(dom: Domain, mesh: Mesh, axes, n: int,
         )
     if rep_axis is not None and not collectives:
         # no rep-psum to make the output rep-invariant: return the
-        # rep-stacked partial grids instead (reconciliation probe layout)
+        # rep-stacked partial grids instead
         out_specs = P(rep_axis, ax, ay, None, None, None)
     else:
         out_specs = P(ax, ay, None, None, None)
@@ -396,8 +386,7 @@ def prepare_pd_xt(
 
 
 def build_pd_xt(dom: Domain, mesh: Mesh, axes, n: int,
-                ks=km.DEFAULT_KS, kt=km.DEFAULT_KT, rep_axis=None,
-                collectives: bool = True):
+                ks=km.DEFAULT_KS, kt=km.DEFAULT_KT, rep_axis=None):
     """PD split over (X, T) instead of (X, Y) — §Perf STKDE iteration.
 
     The halo a subdomain exchanges is its boundary thickened by the
@@ -405,9 +394,6 @@ def build_pd_xt(dom: Domain, mesh: Mesh, axes, n: int,
     Hs-wide ones. For long-duration instances (eBird: Gt=2435, Ht=5 vs
     Hs=30) this cuts halo traffic ~3x at identical work. Input layout:
     (A, B, cap, 3) buckets over (x-tile, t-tile).
-    ``collectives=False`` skips the halo ppermute folds (and rep psum) —
-    the reconciliation probe for the planner's ``comm_s`` term; the output
-    is then the unfolded interior (numerically incomplete by design).
     """
     ax, at = axes
     A, B = _mesh_sizes(mesh, axes)
@@ -429,10 +415,7 @@ def build_pd_xt(dom: Domain, mesh: Mesh, axes, n: int,
     else:
         in_specs = (P(rep_axis, ax, at, None, None),
                     P(rep_axis, ax, at, None))
-    if rep_axis is not None and not collectives:
-        out_specs = P(rep_axis, ax, at, None, None, None)
-    else:
-        out_specs = P(ax, at, None, None, None)
+    out_specs = P(ax, at, None, None, None)
 
     def f(pts_blk, val_blk):
         i = jax.lax.axis_index(ax).astype(jnp.float32)
@@ -446,9 +429,6 @@ def build_pd_xt(dom: Domain, mesh: Mesh, axes, n: int,
             ]
         )
         L = _pb(p - shift, ldom, variant="sym", ks=ks, kt=kt, n_total=n)
-        if not collectives:
-            out = L[Hs : Hs + gx_loc, :, Ht : Ht + gt_loc][None, None]
-            return out if rep_axis is None else out[None]
         # fold halos: X phase (full-t slabs), then T phase (interior-x)
         fwd_x = [(k, k + 1) for k in range(A - 1)]
         bwd_x = [(k, k - 1) for k in range(1, A)]
@@ -523,8 +503,7 @@ def prepare_pd_xyt(
 
 
 def build_pd_xyt(dom: Domain, mesh: Mesh, axes, n: int,
-                 ks=km.DEFAULT_KS, kt=km.DEFAULT_KT,
-                 collectives: bool = True):
+                 ks=km.DEFAULT_KS, kt=km.DEFAULT_KT):
     """Full 3-D PD decomposition (the paper's A×B×C) for multi-pod meshes.
 
     Splits (X, Y, T) over three mesh axes — e.g. pod×data×model = 2×16×16
@@ -533,9 +512,6 @@ def build_pd_xyt(dom: Domain, mesh: Mesh, axes, n: int,
     while halo traffic stays proportional to subdomain surface; the
     cross-pod (DCN) direction is X, which exchanges only two
     Hs-thick slabs per build.
-    ``collectives=False`` skips all three halo-fold phases — the
-    reconciliation probe for the planner's ``comm_s`` term; the output is
-    then the unfolded interior (numerically incomplete by design).
     """
     ax, ay, at = axes
     A, B, C = _mesh_sizes(mesh, axes)
@@ -569,9 +545,6 @@ def build_pd_xyt(dom: Domain, mesh: Mesh, axes, n: int,
             ]
         )
         L = _pb(p - shift, ldom, variant="sym", ks=ks, kt=kt, n_total=n)
-        if not collectives:
-            out = L[Hs : Hs + gx_loc, Hs : Hs + gy_loc, Ht : Ht + gt_loc]
-            return out[None, None, None]
         # X phase (full-(y,t) slabs) -> Y phase (interior-x) -> T phase
         fwd = lambda nn: [(q, q + 1) for q in range(nn - 1)]
         bwd = lambda nn: [(q, q - 1) for q in range(1, nn)]
@@ -722,14 +695,11 @@ def prepare_dd_lpt(
 def build_dd_lpt(dom: Domain, mesh: Mesh, axes, n: int,
                  tile: Tuple[int, int, int], k: int, cap: int,
                  ntiles: Tuple[int, int, int],
-                 ks=km.DEFAULT_KS, kt=km.DEFAULT_KT,
-                 collectives: bool = True):
+                 ks=km.DEFAULT_KS, kt=km.DEFAULT_KT):
     """Jitted DD-LPT over LPT-placed tile soup (dry-run lowerable).
 
     Static parameters (``tile``, ``k``, ``cap``, ``ntiles``) come from
-    ``prepare_dd_lpt``'s ctx. ``collectives=False`` skips the tile-soup
-    assembly psum and returns the device-stacked partial grids — the
-    reconciliation probe for the planner's ``comm_s`` term.
+    ``prepare_dd_lpt``'s ctx.
     """
     ax, ay = axes
     bx, by, bt = tile
@@ -772,14 +742,8 @@ def build_dd_lpt(dom: Domain, mesh: Mesh, axes, n: int,
             jnp.zeros((Gxp, Gyp, Gtp), jnp.float32), (ax, ay), to="varying"
         )
         g = jax.lax.fori_loop(0, k, place, g0)
-        if collectives:
-            return jax.lax.psum(g, (ax, ay))
-        return g[None]
+        return jax.lax.psum(g, (ax, ay))
 
-    out_specs = (
-        P(None, None, None) if collectives
-        else P((ax, ay), None, None, None)
-    )
     return jax.jit(shard_map(
         f,
         mesh=mesh,
@@ -788,7 +752,7 @@ def build_dd_lpt(dom: Domain, mesh: Mesh, axes, n: int,
             P((ax, ay), None, None),
             P((ax, ay), None, None),
         ),
-        out_specs=out_specs,
+        out_specs=P(None, None, None),
     ))
 
 

@@ -19,7 +19,6 @@ from repro.core.geometry import Domain
 from repro.core import bucketing
 from repro.core import kernels_math as km
 from repro.obs import trace as obs_trace
-from . import ref as _ref
 from .stkde_tile import CHUNK, stkde_tiles_pallas
 
 
@@ -59,7 +58,6 @@ def stkde_tiled(
     chunk: int = CHUNK,
     ks: km.SpatialKernel = km.DEFAULT_KS,
     kt: km.TemporalKernel = km.DEFAULT_KT,
-    use_ref: bool = False,
     mode: str = "compiled",
 ) -> jnp.ndarray:
     """STKDE density grid via the tiled PB-SYM GEMM kernel.
@@ -78,11 +76,7 @@ def stkde_tiled(
                                 bytes=lanes.nbytes + tile_of.nbytes):
                 lanes, tile_of = jnp.asarray(lanes), jnp.asarray(tile_of)
         with obs_trace.span("stkde.tiled.dispatch"):
-            if use_ref:
-                padded = _ref.stkde_tiles_ref(lanes, tile_of, dom, tile,
-                                              len(pts), ks, kt)
-            else:
-                padded = stkde_tiles_pallas(lanes, tile_of, dom, tile,
-                                            len(pts), ks, kt, mode=mode)
+            padded = stkde_tiles_pallas(lanes, tile_of, dom, tile,
+                                        len(pts), ks, kt, mode=mode)
         with obs_trace.span("stkde.tiled.reassemble"):
             return padded[: dom.Gx, : dom.Gy, : dom.Gt]

@@ -1,37 +1,20 @@
-"""Unified observability: tracing, metrics, shared timer, reconciliation.
+"""Unified observability: tracing and metrics.
 
   trace      nestable spans -> Chrome-trace/Perfetto JSON
   metrics    process-global counters / gauges / log-scale histograms
-  timing     the one benchmark timer (warmup + block_until_ready in one place)
-  reconcile  planner predicted-vs-measured phase reconciliation
 
-``trace``/``metrics``/``timing`` are dependency-free (stdlib; jax touched
-lazily). ``reconcile`` pulls in core/distributed, so it is loaded lazily to
-keep ``repro.obs`` importable from anywhere in the stack without cycles.
+Both are dependency-free (stdlib; jax touched lazily), so ``repro.obs`` is
+importable from anywhere in the stack.
 """
-from . import metrics, timing, trace
+from . import metrics, trace
 from .metrics import counter, gauge, histogram
-from .timing import timeit
 from .trace import span
 
 __all__ = [
     "trace",
     "metrics",
-    "timing",
-    "reconcile",
     "span",
-    "timeit",
     "counter",
     "gauge",
     "histogram",
 ]
-
-
-def __getattr__(name):
-    if name == "reconcile":
-        import importlib
-
-        mod = importlib.import_module(".reconcile", __name__)
-        globals()["reconcile"] = mod
-        return mod
-    raise AttributeError(f"module 'repro.obs' has no attribute {name!r}")

@@ -184,7 +184,7 @@ class Tracer:
     def ingest(self, events: List[Dict[str, Any]],
                pid: Optional[int] = None) -> None:
         """Merge Chrome events produced by another process (e.g. the
-        8-device benchmark subprocess) into this tracer's timeline."""
+        8-device test subprocess) into this tracer's timeline."""
         with self._lock:
             for e in events:
                 e = dict(e)

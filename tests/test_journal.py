@@ -1,15 +1,14 @@
 """Crash-safe resumable STKDE tests: chunked execution, the durable
 progress journal (corruption salvage, fingerprint refusal), SIGKILL
 mid-run + bit-identical resume, mesh-shrink re-planning on device loss,
-serve partial answers, and the calibrated host planner model."""
-import json
+and serve partial answers."""
 import os
 import time
 
 import numpy as np
 import pytest
 
-from repro.core import Domain, clustered_events, plan
+from repro.core import Domain, clustered_events
 from repro.core.api import stkde, stkde_chunked
 from repro.core.datasets import STKDEInstance
 from repro.core.pb import pb
@@ -212,33 +211,6 @@ def test_serve_partial_answer(tmp_path):
     assert np.allclose(scaled.grid, part.grid / ans.coverage)
     with pytest.raises(ReproValidationError):
         stkde_partial_answer(str(tmp_path / "empty"))
-
-
-# --------------------------------------------------- host calibration
-def test_host_model_calibrated_against_committed_reconcile():
-    """plan.HOST is calibrated from results/bench/reconcile.json: every
-    registry strategy has a compute row within 5x of its prediction
-    (the acceptance band for full-strategy reconciliation)."""
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "results", "bench", "reconcile.json")
-    reports = json.load(open(path))  # one report dict per benchmarked run
-    rows = [r for rep in reports for r in rep["rows"]]
-    # the committed run predicts with the calibrated HOST model
-    assert all(rep["hw"] == "host" for rep in reports)
-    compute = {r["strategy"]: r for r in rows if r["term"] == "compute_s"}
-    assert set(plan.probed_strategies()) <= set(compute)
-    for strat in plan.probed_strategies():
-        r = compute[strat]
-        assert r["measured_s"] > 0 and r["predicted_s"] > 0, r
-        ratio = r["measured_s"] / r["predicted_s"]
-        assert 1 / 5 < ratio < 5, (r, ratio)
-    # re-fitting from the same file lands near the committed constants,
-    # on both the scatter rate and the dd_lpt tile-path derate
-    cal = plan.calibrate_host(path, base=plan.HOST)
-    assert 0.5 < cal.peak_flops / plan.HOST.peak_flops < 2.0
-    assert 0.5 < cal.mxu_derate / plan.HOST.mxu_derate < 2.0
-    # sanity: calibration moved far from the accelerator-class seed
-    assert plan.HOST_SEED.peak_flops / plan.HOST.peak_flops > 1e3
 
 
 def test_shrink_mesh_single_device_exhausts():

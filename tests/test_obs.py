@@ -1,11 +1,10 @@
-"""Observability layer: spans, Chrome export, metrics, timer, reconcile."""
+"""Observability layer: spans, Chrome export, metrics."""
 import json
 
 import numpy as np
 import pytest
 
-from repro.obs import metrics, timeit, trace
-from util_subproc import run_with_devices
+from repro.obs import metrics, trace
 
 
 # ------------------------------------------------------------------ trace
@@ -130,65 +129,3 @@ def test_registry_reset_between_tests_b():
     # ordering with _a doesn't matter: neither test may see the other's state
     assert "leak.probe" not in metrics.get_registry().names()
     metrics.counter("leak.probe").inc()
-
-
-# ----------------------------------------------------------------- timing
-def test_timeit_records_span_and_histogram():
-    res = timeit(lambda: sum(range(100)), reps=3, warmup=1, name="t.work")
-    assert len(res.times) == 3
-    assert res.best <= res.mean
-    assert len(trace.get_tracer().spans("bench.t.work")) == 3
-    assert metrics.export()["histograms"]["t.work_s"]["count"] == 3
-
-
-# -------------------------------------------------------------- reconcile
-def test_reconcile_smoke_8dev_all_registry_strategies():
-    """reconcile.run on a 2x2x2 mesh probes every PROBED strategy and
-    emits all four terms per strategy — no silently missing rows."""
-    out = run_with_devices(
-        """
-import json
-import jax
-import numpy as np
-from repro.core import Domain, clustered_events
-from repro.obs import reconcile
-
-dom = Domain(gx=48.0, gy=48.0, gt=16.0, sres=1.0, tres=1.0, hs=3.0, ht=2.0)
-pts = clustered_events(1500, dom, seed=0)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-res = reconcile.run(pts, dom, mesh, reps=1)
-res["_probed"] = list(reconcile.PROBED)
-print("RESULT" + json.dumps(res))
-""",
-        n_devices=8,
-    )
-    line = [ln for ln in out.splitlines() if ln.startswith("RESULT")][0]
-    res = json.loads(line[len("RESULT"):])
-    strategies = {r["strategy"] for r in res["rows"]}
-    assert strategies == set(res["_probed"])
-    assert {"dr", "dd", "pd", "pd_xt", "pd_xyt", "dd_lpt",
-            "hybrid"} <= strategies
-    for strat in strategies:
-        terms = {r["term"] for r in res["rows"] if r["strategy"] == strat}
-        assert terms == reconcile_terms(), (strat, terms)
-    for r in res["rows"]:
-        assert r["measured_s"] >= 0
-        if r["predicted_s"] is not None:
-            assert r["rel_err"] is not None
-    assert "strategy" in res["report"]
-
-
-def test_measure_strategy_error_lists_registry_keys():
-    from repro.obs import reconcile
-
-    with pytest.raises(ValueError) as ei:
-        reconcile.measure_strategy(
-            np.zeros((1, 3), np.float32), None, None, "nope")
-    for name in reconcile.PROBED:
-        assert name in str(ei.value)
-
-
-def reconcile_terms():
-    from repro.obs import reconcile
-
-    return set(reconcile.TERMS)

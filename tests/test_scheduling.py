@@ -134,7 +134,7 @@ class TestPlanner:
             assert v["total_s"] > 0
 
     @pytest.mark.parametrize("platform,kind,want", [
-        ("cpu", "cpu", "HOST"),
+        ("cpu", "cpu", "V5E"),
         ("tpu", "TPU v5 lite", "V5E"),
         ("tpu", "TPU v99 imaginary", None),
     ])
@@ -154,3 +154,45 @@ class TestPlanner:
                 plan.default_hw()
         else:
             assert plan.default_hw() is getattr(plan, want)
+
+
+# The planner's picks on a 2x2 ``data`` x ``model`` mesh for every Table 2
+# instance up to PollenUS's 588,189 events (eBird's 292 M are left out),
+# priced with the CPU's default hardware model, which is the v5e's.
+# "chip" marks a pick that a run on four v5e chips confirms: Flu_Mr-Hb is
+# the ``flu_mr_hb_x4.build`` cell, and PERF.md records ``dd`` for
+# PollenUS_Hr-Lb on 2x2.
+MESH_PLANS_2X2 = {
+    "Dengue_Lr-Lb": "dd",
+    "Dengue_Lr-Hb": "dd",
+    "Dengue_Hr-Lb": "dd",
+    "Dengue_Hr-Hb": "dd",
+    "Dengue_Hr-VHb": "dd",
+    "PollenUS_Lr-Lb": "pd",
+    "PollenUS_Hr-Lb": "dd",       # chip
+    "PollenUS_Hr-Mb": "dd_lpt",
+    "PollenUS_Hr-Hb": "dd_lpt",
+    "PollenUS_VHr-Lb": "dd",
+    "PollenUS_VHr-VLb": "dd",
+    "Flu_Lr-Lb": "dd",
+    "Flu_Lr-Hb": "dd",
+    "Flu_Mr-Lb": "dd",
+    "Flu_Mr-Hb": "dd",            # chip
+    "Flu_Hr-Lb": "dd",
+    "Flu_Hr-Hb": "dd",
+}
+
+
+@pytest.mark.parametrize("name", sorted(MESH_PLANS_2X2))
+def test_mesh_plan_on_2x2(name):
+    """A CPU run plans a mesh exactly as the v5e does."""
+    import types
+
+    from repro.core import get_instance
+    from repro.core.api import _auto_strategy
+
+    inst = get_instance(name)
+    mesh = types.SimpleNamespace(shape={"data": 2, "model": 2})
+    got = _auto_strategy(inst.domain(), inst.n, mesh, ("data", "model"),
+                         None, inst.points())
+    assert got == MESH_PLANS_2X2[name]

@@ -136,14 +136,13 @@ def test_heavy_clustering_with_lpt():
 
 
 def test_nocomm_builds_identical_on_single_device():
-    """collectives=False probes are numerically identical to the full
-    builds on a 1-device mesh (empty ppermute perms contribute zeros,
+    """PD built with collectives=False is numerically identical to the
+    full build on a 1-device mesh (empty ppermute perms contribute zeros,
     size-1 psum is the identity)."""
     code = COMMON + textwrap.dedent(
         """
         from repro.distributed.stkde_dist import (
-            prepare_pd, build_pd, prepare_pd_xt, build_pd_xt,
-            prepare_pd_xyt, build_pd_xyt, prepare_hybrid)
+            prepare_pd, build_pd, prepare_hybrid)
 
         dom = Domain(gx=48., gy=48., gt=16., sres=1., tres=1., hs=3., ht=2.)
         pts = clustered_events(1500, dom, seed=7)
@@ -159,21 +158,6 @@ def test_nocomm_builds_identical_on_single_device():
         np.testing.assert_array_equal(full, noc)
         print("pd ok")
 
-        args = prepare_pd_xt(pts, dom, mesh, w2)
-        full = np.asarray(build_pd_xt(dom, mesh, w2, n)(*args))
-        noc = np.asarray(build_pd_xt(dom, mesh, w2, n,
-                                     collectives=False)(*args))
-        np.testing.assert_array_equal(full, noc)
-        print("pd_xt ok")
-
-        ax3 = ("pod", "data", "model")
-        args = prepare_pd_xyt(pts, dom, mesh, ax3)
-        full = np.asarray(build_pd_xyt(dom, mesh, ax3, n)(*args))
-        noc = np.asarray(build_pd_xyt(dom, mesh, ax3, n,
-                                      collectives=False)(*args))
-        np.testing.assert_array_equal(full, noc)
-        print("pd_xyt ok")
-
         args = prepare_hybrid(pts, dom, mesh, w2, rep_axis="pod")
         full = np.asarray(build_pd(dom, mesh, w2, n,
                                    rep_axis="pod")(*args))
@@ -188,20 +172,19 @@ def test_nocomm_builds_identical_on_single_device():
 
 
 def test_nocomm_builds_differ_only_by_halo_terms_8dev():
-    """On a real 2x2x2 mesh the collectives=False probes differ from the
-    full builds only in the halo bands / rep-psum: subdomain interiors
+    """On a real 2x2x2 mesh PD built with collectives=False differs from
+    the full build only in the halo bands / rep-psum: subdomain interiors
     more than one bandwidth from a cut boundary are bitwise identical,
     and the boundary bands do differ (comm moves real mass)."""
     code = COMMON + textwrap.dedent(
         """
         from repro.distributed.stkde_dist import (
-            prepare_pd, build_pd, prepare_pd_xt, build_pd_xt,
-            prepare_pd_xyt, build_pd_xyt, prepare_hybrid)
+            prepare_pd, build_pd, prepare_hybrid)
 
         dom = Domain(gx=48., gy=48., gt=16., sres=1., tres=1., hs=3., ht=2.)
         pts = clustered_events(1500, dom, seed=7)
         n = len(pts)
-        Hs, Ht = dom.Hs, dom.Ht
+        Hs = dom.Hs
         mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                              axis_types=(AxisType.Auto,)*3)
         w2 = ("data", "model")
@@ -220,22 +203,6 @@ def test_nocomm_builds_differ_only_by_halo_terms_8dev():
                                   collectives=False)(*args))
         ix = np.s_[:, :, Hs:-Hs, Hs:-Hs, :]
         split("pd", full, noc, ix)
-
-        # pd_xt: Hs-wide x halos, Ht-wide t halos, y uncut
-        args = prepare_pd_xt(pts, dom, mesh, w2)
-        full = np.asarray(build_pd_xt(dom, mesh, w2, n)(*args))
-        noc = np.asarray(build_pd_xt(dom, mesh, w2, n,
-                                     collectives=False)(*args))
-        split("pd_xt", full, noc, np.s_[:, :, Hs:-Hs, :, Ht:-Ht])
-
-        # pd_xyt: all three directions cut
-        ax3 = ("pod", "data", "model")
-        args = prepare_pd_xyt(pts, dom, mesh, ax3)
-        full = np.asarray(build_pd_xyt(dom, mesh, ax3, n)(*args))
-        noc = np.asarray(build_pd_xyt(dom, mesh, ax3, n,
-                                      collectives=False)(*args))
-        split("pd_xyt", full, noc,
-              np.s_[:, :, :, Hs:-Hs, Hs:-Hs, Ht:-Ht])
 
         # hybrid: nocomm is rep-stacked; away from halo bands the full
         # build is exactly the rep-sum of the unfolded partials

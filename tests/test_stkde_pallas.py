@@ -45,7 +45,10 @@ def test_kernel_chunk_sizes(chunk):
     """Every panel size gives the oracle's grid and the scatter's."""
     dom = Domain(gx=32, gy=32, gt=16, sres=1.0, tres=1.0, hs=3.0, ht=2.0)
     pts = _make(dom, 600, seed=11)
-    want = np.asarray(stkde_tiled(pts, dom, chunk=chunk, use_ref=True))
+    lanes, tile_of, tile = tiled_inputs(pts, dom, chunk=chunk)
+    want = stkde_tiles_ref(jnp.asarray(lanes), jnp.asarray(tile_of), dom,
+                           tile, len(pts))
+    want = np.asarray(want)[: dom.Gx, : dom.Gy, : dom.Gt]
     got = np.asarray(stkde_tiled(pts, dom, chunk=chunk, mode="interpret"))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-9)
     np.testing.assert_allclose(got, np.asarray(pb(pts, dom)),

@@ -1,7 +1,7 @@
 """Run a python snippet in a subprocess with N fake XLA host devices.
 
 Used by multi-device tests so the main pytest process keeps seeing exactly
-one CPU device (required by the smoke tests and benchmarks).
+one CPU device (required by the smoke tests).
 """
 from __future__ import annotations
 
